@@ -11,6 +11,8 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def main() -> None:
     sys.path.insert(0, _ROOT)
     sys.path.insert(0, os.path.join(_ROOT, "src"))
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (async_overlap, fleet_scaleout, kernel_tuner,
                             roofline, scale_soak, scrub_overhead,
                             table1_overhead, table2_shell, table3_matmul,

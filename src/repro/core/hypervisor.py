@@ -13,9 +13,9 @@ Serving traffic enters through the *tenant session* API
 vSlice, and per-step telemetry flows into the straggler monitor so hot
 tenants get migrated like any other workload.
 
-On this CPU container the "physical device" is a simulated inventory; the
-dataplane executes on the host jax device. On a real cluster the same control
-plane drives per-slice jax meshes (launch/mesh.py builds them).
+The "physical devices" are an inventory description; the serving fleet
+backs inventory device *i* with ``jax.devices()[i % n]`` (one engine per
+chip on a TPU host; every device shares the one device on CPU).
 """
 from __future__ import annotations
 

@@ -6,8 +6,9 @@ FPGA mapping:
                                              into a vSlice while co-tenants run
 
 The ``ProgramCache`` is the "bitfile library": keyed by (core fingerprint,
-input avals, kernel geometry). ``configure`` populates it (slow path);
-``partial_reconfigure`` swaps a cached executable into a slice (fast path).
+input avals, kernel geometry, device placement). ``configure`` populates
+it (slow path); ``partial_reconfigure`` swaps a cached executable into a
+slice (fast path).
 Latencies of both paths are what benchmarks/table1_overhead.py measures.
 
 The cache also persists auto-tuner winners: a side store maps
@@ -44,6 +45,18 @@ def _aval_key(tree) -> str:
     return hashlib.sha256(repr(leaves).encode()).hexdigest()[:16]
 
 
+def _device_key(tree) -> str:
+    """Ids of the devices the example inputs are placed on ("" when they
+    carry no placement): an executable is compiled for its devices, so a
+    second chip must never bind the first chip's program."""
+    ids = set()
+    for x in jax.tree.leaves(tree):
+        sharding = getattr(x, "sharding", None)
+        if sharding is not None:
+            ids.update(d.id for d in sharding.device_set)
+    return ",".join(map(str, sorted(ids)))
+
+
 @dataclass
 class ProgramEntry:
     fingerprint: str
@@ -57,9 +70,9 @@ class ProgramEntry:
 class ProgramCache:
     """Executable cache ≈ the provider's pre-built bitfile store (BAaaS).
 
-    Doubly indexed: by full key (fingerprint, input avals, kernel geometry)
-    for PR swaps, and by fingerprint alone for the hypervisor's execute
-    path. Optionally bounded: ``max_entries`` evicts least-recently-used
+    Doubly indexed: by full key (fingerprint, input avals, kernel geometry,
+    device placement) for PR swaps, and by fingerprint alone for the
+    hypervisor's execute path. Optionally bounded: ``max_entries`` evicts least-recently-used
     programs, the analogue of a finite on-device bitfile library.
 
     Kernel geometry is part of the key: a tuned program and the default
@@ -71,10 +84,10 @@ class ProgramCache:
     def __init__(self, max_entries: Optional[int] = None):
         from collections import OrderedDict
         self._lock = threading.Lock()
-        self._entries: "OrderedDict[Tuple[str, str, str], ProgramEntry]" = \
+        self._entries: "OrderedDict[Tuple[str, ...], ProgramEntry]" = \
             OrderedDict()
         self._by_fp: Dict[str, ProgramEntry] = {}
-        self._fp_key: Dict[str, Tuple[str, str, str]] = {}
+        self._fp_key: Dict[str, Tuple[str, ...]] = {}
         self._tuned: Dict[Tuple[str, str], dict] = {}
         self.max_entries = max_entries
         self.hits = 0
@@ -82,8 +95,9 @@ class ProgramCache:
         self.evictions = 0
 
     def key(self, fp: str, example_inputs,
-            geometry: str = "") -> Tuple[str, str, str]:
-        return (fp, _aval_key(example_inputs), geometry)
+            geometry: str = "") -> Tuple[str, str, str, str]:
+        return (fp, _aval_key(example_inputs), geometry,
+                _device_key(example_inputs))
 
     def get(self, key) -> Optional[ProgramEntry]:
         with self._lock:
@@ -208,13 +222,7 @@ class Reconfigurator:
             else jitted.lower(example_inputs)
         compiled = lowered.compile()
         dt = time.perf_counter() - t0
-        cost = {}
-        try:
-            cost = compiled.cost_analysis() or {}
-        except Exception:
-            pass
-        if isinstance(cost, (list, tuple)):   # older jax returns [dict]
-            cost = cost[0] if cost else {}
+        cost = compiled.cost_analysis() or {}
         entry = ProgramEntry(
             fingerprint=fp, compiled=compiled,
             lowered_text=lowered.as_text() if keep_hlo else None,

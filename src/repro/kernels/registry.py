@@ -67,12 +67,16 @@ def dtype_bytes(dtype: str) -> int:
 
 def check_decode_block(cache_len: int, block_k: int) -> Optional[str]:
     """decode_attention sweeps the cache in blocks of ``min(block_k, L)``
-    and requires L to divide evenly (kernels/decode_attention.py)."""
+    and requires L to divide evenly (kernels/decode_attention.py). A block
+    shorter than the cache is the lane dim of the (1, 1, bk) position
+    block, so it must be a multiple of the lane width."""
     if block_k < 1:
         return f"decode block_k={block_k} < 1"
     bk = min(block_k, cache_len)
     if cache_len % bk != 0:
         return f"cache_len={cache_len} not divisible by block_k={bk}"
+    if bk < cache_len and bk % LANE != 0:
+        return f"decode block_k={bk} not a multiple of {LANE}"
     return None
 
 
@@ -110,15 +114,18 @@ def check_head_alignment(head_dim: int) -> Optional[str]:
 # BlockSpec + scratch shapes inside each kernel. Used for hard pruning.
 # ---------------------------------------------------------------------------
 
-def decode_vmem_bytes(block_k: int, head_dim: int, kv_dtype: str) -> int:
-    """decode_attention grid step: q (D,) fp32 + k/v blocks (bk, D) + kpos
-    (bk,) + fp32 scratch acc (D,) + m/l (1,)."""
+def decode_vmem_bytes(block_k: int, head_dim: int, kv_dtype: str,
+                      group: int) -> int:
+    """decode_attention grid step for ``group`` query heads per KV head:
+    q and out (g, D) fp32 + k/v blocks (bk, D) + kpos (1, bk) + int8 row
+    scales (1, bk) x2 + fp32 scratch acc (g, D) and m/l (g, 1)."""
     kvb = dtype_bytes(kv_dtype)
-    q = head_dim * 4
+    q_out = 2 * group * head_dim * 4
     kv = 2 * block_k * head_dim * kvb
     kpos = block_k * 4
-    scratch = head_dim * 4 + 2 * 4
-    return q + kv + kpos + scratch
+    scales = 2 * block_k * 4 if kvb == 1 else 0
+    scratch = group * head_dim * 4 + 2 * group * 4
+    return q_out + kv + kpos + scales + scratch
 
 
 def flash_vmem_bytes(block_q: int, block_k: int, head_dim: int,

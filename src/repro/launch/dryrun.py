@@ -1,4 +1,7 @@
 import os
+# a CPU tool: 512 virtual host devices, and never the TPU (so neither the
+# dry-run nor launch/sweep.py's children take a chip another process holds)
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
                            + os.environ.get("XLA_FLAGS", ""))
 

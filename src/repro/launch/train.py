@@ -21,6 +21,7 @@ import numpy as np
 from repro.ckpt import restore, save
 from repro.configs import SHAPES, get_config, reduced
 from repro.data import DataConfig, DataPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import get_model
 from repro.optim import AdamWConfig
@@ -45,6 +46,7 @@ def main():
     ap.add_argument("--model", type=int, default=1, help="mesh model axis")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduce:
         cfg = reduced(cfg)

@@ -451,9 +451,13 @@ def attn_decode(p, x, positions, cache, opts: AttnOpts, update_cache=True):
         new["pos"] = cache["pos"].at[b, idx].set(positions[:, 0])
         cache = new
     dmode = _decode_kernel_mode(opts)
-    if (dmode is not None and opts.causal and not opts.softcap
-            and kreg.check_decode_block(cache["k"].shape[1],
-                                        opts.decode_block_k) is None):
+    # soft-capped scores have no kernel yet: those layers decode on XLA
+    if dmode is not None and opts.causal and not opts.softcap:
+        reason = kreg.check_decode_block(cache["k"].shape[1],
+                                         opts.decode_block_k)
+        if reason is not None:
+            raise ValueError(f"decode kernel cannot sweep this cache: "
+                             f"{reason}")
         y = _decode_kernel_attend(q, cache, positions, opts, dmode)
     else:
         if quant:

@@ -7,7 +7,9 @@ everyone on ONE engine, so a migration only moved bookkeeping. The
 ``GatewayFleet`` closes that gap:
 
   * one ``BatchingEngine`` per ACTIVE physical device — the engine IS the
-    device's dataplane, its KV caches are that device's memory;
+    device's dataplane, its KV caches are that device's memory. Hypervisor
+    device *i* is backed by ``jax.devices()[i % n]``: each engine holds
+    its own params replica, caches and decode executable on that chip;
   * ``open_session`` places a tenant on the engine backing its vSlice's
     device, so the DeviceDB's pack-first energy policy decides where
     decoding actually happens;
@@ -38,6 +40,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
 
 from repro.analysis.lifecycle import sanitizer
 from repro.configs.base import GeometryConfig
@@ -82,6 +85,16 @@ class _ProgramBundle:
     n_slots: int
     page_size: int
     fingerprint: Optional[str] = None
+
+    def example_on(self, device: jax.Device) -> tuple:
+        """The abstract example placed on ``device``: the executable the
+        reconfigurator compiles from it runs there, and the ProgramCache
+        keys it apart from every other chip's."""
+        sharding = SingleDeviceSharding(device)
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=sharding),
+            self.example)
 
 
 @dataclasses.dataclass
@@ -217,8 +230,9 @@ class GatewayFleet:
         bundle = self._make_bundle(None)
         self._default_bundle = bundle
         self._decode_fn = bundle.decode_fn
-        self._example = bundle.example
         self._desc = bundle.desc
+        self._example = bundle.example_on(
+            self.jax_device(next(iter(hv.db.devices))))
         entry, dt, hit = hv.reconfig.partial_reconfigure(
             self._decode_fn, self._example, static_desc=self._desc)
         self.program_fingerprint = bundle.fingerprint = entry.fingerprint
@@ -313,27 +327,36 @@ class GatewayFleet:
     # ------------------------------------------------------------------
     # Engine lifecycle (one per active device)
     # ------------------------------------------------------------------
+    def jax_device(self, device_id: str) -> jax.Device:
+        """The chip backing hypervisor device ``device_id``: the i-th
+        inventory device maps to ``jax.devices()[i % n]`` (with one chip,
+        or on CPU, every hypervisor device shares it)."""
+        devices = jax.devices()
+        return devices[list(self.hv.db.devices).index(device_id)
+                       % len(devices)]
+
     def _ensure_engine(self, device_id: str) -> BatchingEngine:
         eng = self._engines.get(device_id)
         if eng is not None:
             return eng
         bundle = self._bundle_for(device_id)
+        device = self.jax_device(device_id)
         eng = BatchingEngine(bundle.model, self.params,
                              n_slots=bundle.n_slots,
                              max_len=self.max_len, eos_id=self.eos_id,
                              id_counter=self._req_ids, paged=self.paged,
                              page_size=bundle.page_size,
-                             cache_pages=self.cache_pages)
+                             cache_pages=self.cache_pages, device=device)
         entry, dt, hit = self.hv.reconfig.partial_reconfigure(
-            bundle.decode_fn, bundle.example, static_desc=bundle.desc,
-            geometry=bundle.geometry)
+            bundle.decode_fn, bundle.example_on(device),
+            static_desc=bundle.desc, geometry=bundle.geometry)
         bundle.fingerprint = entry.fingerprint
         eng.use_program(entry.compiled)
         eng.on_step = lambda active, ms, dev=device_id: \
             self._on_step(dev, active, ms)
         eng.on_finish = self._on_finish
         self._engines[device_id] = eng
-        self.hv._log("engine_up", device=device_id,
+        self.hv._log("engine_up", device=device_id, chip=device.id,
                      fingerprint=entry.fingerprint, swap_s=dt, cache_hit=hit,
                      geometry=bundle.geometry or "default")
         return eng
@@ -384,9 +407,10 @@ class GatewayFleet:
             # bundle of the device's class, so an autotuned fleet binds
             # tuned geometry with zero operator input
             bundle = self._bundle_for(vs.device_id)
-            self.hv.program_slice(vs.slice_id, bundle.decode_fn,
-                                  bundle.example, static_desc=bundle.desc,
-                                  geometry=bundle.geometry)
+            self.hv.program_slice(
+                vs.slice_id, bundle.decode_fn,
+                bundle.example_on(self.jax_device(vs.device_id)),
+                static_desc=bundle.desc, geometry=bundle.geometry)
             engine.set_tenant_share(tenant, slots)
             engine.set_tenant_weight(tenant, slots)
             if self.paged:

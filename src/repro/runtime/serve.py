@@ -267,7 +267,8 @@ class BatchingEngine:
                  id_counter: Optional[Iterator[int]] = None,
                  paged: bool = False, page_size: int = 16,
                  cache_pages: Optional[int] = None,
-                 scrub_on_free: bool = True):
+                 scrub_on_free: bool = True,
+                 device: Optional[jax.Device] = None):
         # Slot recycling relies on position-masked KV caches (stale entries
         # carry positions > current and are masked out). SSM state has no
         # such masking, so the engine serves attention-family models; SSM
@@ -278,7 +279,12 @@ class BatchingEngine:
         if prefill_mode not in ("batched", "legacy"):
             raise ValueError(f"unknown prefill_mode {prefill_mode!r}")
         self.model = model
-        self.params = params
+        # the device this engine's dataplane lives on (None: JAX's
+        # default). Its params replica, caches and every host upload are
+        # placed there, so jitted steps run on it.
+        self.device = device
+        self.params = params if device is None \
+            else jax.device_put(params, device)
         self.n_slots = n_slots
         self.max_len = max_len
         self.eos_id = eos_id
@@ -325,7 +331,8 @@ class BatchingEngine:
             self.pool = PagePoolManager(cache_pages, page_size, n_slots,
                                         max_blocks,
                                         scrub_on_free=scrub_on_free)
-            self.caches = model.make_paged_caches(cache_pages, page_size)
+            self.caches = self._alloc(
+                lambda: model.make_paged_caches(cache_pages, page_size))
             self._pos = np.full((n_slots,), -1, np.int32)
             step = make_paged_serve_step(model)
             self._decode = jax.jit(step)
@@ -335,7 +342,8 @@ class BatchingEngine:
             self.page_size = 0
             self.cache_pages = 0
             self.pool = None
-            self.caches = model.make_caches(n_slots, max_len)
+            self.caches = self._alloc(
+                lambda: model.make_caches(n_slots, max_len))
             self._pos = np.zeros((n_slots,), np.int32)
             self._decode = jax.jit(
                 lambda p, c, t, pos: model.decode(p, c, t, pos))
@@ -353,6 +361,16 @@ class BatchingEngine:
         # on every request completion
         self.on_step: Optional[Callable[[Dict[str, int], float], None]] = None
         self.on_finish: Optional[Callable[[Request], None]] = None
+
+    def _put(self, x):
+        """Host -> this engine's device (any pytree of arrays)."""
+        return jax.device_put(x, self.device)
+
+    def _alloc(self, make):
+        """Build a pytree of device arrays directly on this engine's
+        device (no staging copy on the default device)."""
+        with jax.default_device(self.device):
+            return self._put(make())
 
     def use_program(self, compiled: Callable) -> None:
         """Swap in an externally compiled decode executable — the serving
@@ -569,8 +587,8 @@ class BatchingEngine:
             return
         self.caches = _invalidate_pool_pages(
             self.caches,
-            jnp.asarray(np.asarray(sorted(pages),    # rc3e: allow-host-sync
-                                   np.int32)))
+            self._put(np.asarray(sorted(pages),      # rc3e: allow-host-sync
+                                 np.int32)))
 
     def _flush_scrub(self) -> int:
         """Drain the pool's zero-on-free queue with ONE batched jitted
@@ -585,8 +603,8 @@ class BatchingEngine:
         t0 = time.monotonic()
         self.caches = _scrub_pool_pages(
             self.caches,
-            jnp.asarray(np.asarray(sorted(pids),     # rc3e: allow-host-sync
-                                   np.int32)))
+            self._put(np.asarray(sorted(pids),       # rc3e: allow-host-sync
+                                 np.int32)))
         self.scrub_ms += (time.monotonic() - t0) * 1e3
         return len(pids)
 
@@ -746,7 +764,7 @@ class BatchingEngine:
         if pending.buf is not None:
             if self.paged:
                 plan = pending.plan
-                pages = jnp.asarray(                 # rc3e: allow-host-sync
+                pages = self._put(
                     np.asarray(plan.write_pages,     # rc3e: allow-host-sync
                                np.int32))
                 self.caches = _splice_pages(self.caches, pending.buf, pages,
@@ -793,7 +811,7 @@ class BatchingEngine:
         content — that's the point of sharing them)."""
         _, slot_caches = self._prefill(self.params, self._pad_ctx(ctx))
         # admission-time upload of the write-page index vector
-        pages = jnp.asarray(                         # rc3e: allow-host-sync
+        pages = self._put(
             np.asarray(plan.write_pages,             # rc3e: allow-host-sync
                        np.int32))
         self.caches = _splice_pages(self.caches, slot_caches, pages,
@@ -808,7 +826,7 @@ class BatchingEngine:
         toks = np.zeros((1, pad), np.int32)
         toks[0, :n] = ctx
         # prefill prompt upload: once per admission, not per step
-        return jnp.asarray(toks)                     # rc3e: allow-host-sync
+        return self._put(toks)
 
     def _block_tables_dev(self):
         """Device copy of the pool block tables, re-uploaded only when the
@@ -817,8 +835,7 @@ class BatchingEngine:
         reuse the cached array instead of paying an H2D transfer of the
         whole (n_slots, max_blocks) table per generated token."""
         if self._bt_version != self.pool.version:
-            self._bt_cache = jnp.asarray(            # rc3e: allow-host-sync
-                self.pool.block_tables)
+            self._bt_cache = self._put(self.pool.block_tables)
             self._bt_version = self.pool.version
         return self._bt_cache
 
@@ -834,17 +851,14 @@ class BatchingEngine:
             posv = np.full((self.n_slots,), -1, np.int32)
             posv[slot] = pos
             _, self.caches = self._decode(
-                self.params, self.caches,
-                jnp.asarray(tokens),                 # rc3e: allow-host-sync
-                jnp.asarray(posv),                   # rc3e: allow-host-sync
-                self._block_tables_dev())
+                self.params, self.caches, self._put(tokens),
+                self._put(posv), self._block_tables_dev())
         else:
             posv = self._pos.copy()
             posv[slot] = pos
             _, self.caches = self._decode(
-                self.params, self.caches,
-                jnp.asarray(tokens),                 # rc3e: allow-host-sync
-                jnp.asarray(posv))                   # rc3e: allow-host-sync
+                self.params, self.caches, self._put(tokens),
+                self._put(posv))
 
     def _prepare_writes(self):
         """Before a paged decode step: every active slot's write position
@@ -874,8 +888,8 @@ class BatchingEngine:
                         self._page_budget_ok(req.tenant, 1):
                     self._flush_scrub()
                     src, dst = self.pool.cow(i, block, req.tenant)
-                    self.caches = _copy_page(self.caches, jnp.int32(src),
-                                             jnp.int32(dst))
+                    self.caches = _copy_page(self.caches, np.int32(src),
+                                             np.int32(dst))
                 else:
                     self._preempt(i)
                 continue
@@ -933,15 +947,12 @@ class BatchingEngine:
         # tiny; the block tables are served from the version-keyed cache
         if self.paged:
             logits, self.caches = self._decode(
-                self.params, self.caches,
-                jnp.asarray(tokens),                 # rc3e: allow-host-sync
-                jnp.asarray(self._pos),              # rc3e: allow-host-sync
-                self._block_tables_dev())
+                self.params, self.caches, self._put(tokens),
+                self._put(self._pos), self._block_tables_dev())
         else:
             logits, self.caches = self._decode(
-                self.params, self.caches,
-                jnp.asarray(tokens),                 # rc3e: allow-host-sync
-                jnp.asarray(self._pos))              # rc3e: allow-host-sync
+                self.params, self.caches, self._put(tokens),
+                self._put(self._pos))
         # argmax on device: fetch (n_slots,) int32 ids, not the full
         # (n_slots, 1, vocab) logits tensor
         next_ids = np.asarray(                       # rc3e: allow-host-sync
@@ -1044,8 +1055,8 @@ class BatchingEngine:
         self._flush_scrub()
         pages = [self.pool.grow(slot, req.tenant) for _ in range(nb)]
         self.caches = _import_pages(
-            self.caches, jax.tree.map(jnp.asarray, payload),
-            jnp.asarray(np.asarray(pages, np.int32)))
+            self.caches, self._put(payload),
+            self._put(np.asarray(pages, np.int32)))
         toks = self._ctx_tokens(req)
         base = len(toks) if ctx_len is None else int(ctx_len)
         # catch-up: KV for positions 0..base-2 arrived with the snapshot;

@@ -25,19 +25,9 @@ from repro.configs.base import ModelConfig
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across jax versions: older releases only ship
-    ``jax.experimental.shard_map`` and spell the check flag ``check_rep``
-    instead of ``check_vma``."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check)
+    """``jax.shard_map`` with the replication check off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def dp_axes(mesh: Mesh):

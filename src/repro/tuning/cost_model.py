@@ -119,7 +119,8 @@ def prune_reason(cand: TunedConfig, cfg: ModelConfig, prof: DeviceProfile,
     hd = cfg.resolved_head_dim
     vmem = max(
         kreg.decode_vmem_bytes(min(cand.decode_block_k, max_len), hd,
-                               "int8" if cfg.kv_quant else cfg.dtype),
+                               "int8" if cfg.kv_quant else cfg.dtype,
+                               group=cfg.n_heads // max(1, cfg.n_kv_heads)),
         kreg.flash_vmem_bytes(min(cand.flash_block_q, max_len),
                               min(cand.flash_block_k, max_len), hd,
                               cfg.dtype),

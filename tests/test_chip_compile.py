@@ -1,0 +1,115 @@
+"""Ahead-of-time compiles for a described TPU v5e chip (no chip needed).
+
+The TPU compiler is installed with JAX, and it compiles for a topology
+that is described but not attached. These tests hand it the serving path's
+kernels and decode steps at smollm-135m's full width, so a block shape or
+VMEM budget the chip would refuse fails here. Nothing runs: results and
+times are out of scope. The topology is described inside a fixture, so
+only the test process that runs this file loads the TPU library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.configs import get_config
+from repro.configs.base import GeometryConfig
+from repro.kernels import ops
+from repro.models import get_model
+from repro.runtime.paged import default_pool_pages
+from repro.runtime.serve import make_paged_serve_step, make_serve_step
+
+B, L = 8, 2048          # the serving fleet's slots and cache length
+PAGE = 16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # a described chip's executables cannot be read back from the
+    # persistent cache: keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
+
+
+def _placed(sharding, tree):
+    return jax.tree.map(lambda x: _sds(sharding, x.shape, x.dtype), tree)
+
+
+def _kernel_model(name="smollm-135m"):
+    cfg = get_config(name)
+    return get_model(cfg.replace(
+        geometry=GeometryConfig(kernel_force="kernel")))
+
+
+@pytest.mark.parametrize("arch,cache_len", [
+    ("smollm-135m", 2048),
+    ("qwen3-moe-30b-a3b", 4096),      # head_dim 128, 8 query heads per KV
+])
+@pytest.mark.parametrize("int8", [False, True])
+def test_decode_kernel_compiles(one_chip, arch, cache_len, int8):
+    cfg = get_config(arch)
+    hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    kv = jnp.int8 if int8 else jnp.bfloat16
+    args = [_sds(one_chip, (B, hq, d), jnp.bfloat16),
+            _sds(one_chip, (B, hkv, cache_len, d), kv),
+            _sds(one_chip, (B, hkv, cache_len, d), kv),
+            _sds(one_chip, (B, cache_len), jnp.int32),
+            _sds(one_chip, (B,), jnp.int32)]
+    if int8:
+        args += [_sds(one_chip, (B, hkv, cache_len), jnp.float32)] * 2
+
+        def f(q, k, v, kpos, cur, ks, vs):
+            return ops.decode_attention(q, k, v, kpos, cur, k_scale=ks,
+                                        v_scale=vs, force="kernel")
+    else:
+        def f(q, k, v, kpos, cur):
+            return ops.decode_attention(q, k, v, kpos, cur, force="kernel")
+    text = jax.jit(f).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_full_width_dense_decode_step(one_chip):
+    """smollm-135m's whole decode step, with the Pallas decode kernel on
+    every layer, as the fleet binds it: 8 slots over 2048 positions."""
+    model = _kernel_model()
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
+    caches = jax.eval_shape(lambda: model.make_caches(B, L))
+    args = (_placed(one_chip, params), _placed(one_chip, caches),
+            _sds(one_chip, (B, 1), jnp.int32),
+            _sds(one_chip, (B,), jnp.int32))
+    compiled = jax.jit(make_serve_step(model)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < 2 * 2 ** 30
+
+
+def test_full_width_paged_decode_step(one_chip):
+    """smollm-135m's paged decode step over the default page pool."""
+    model = _kernel_model()
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
+    pages = default_pool_pages(B, L // PAGE)
+    pool = jax.eval_shape(lambda: model.make_paged_caches(pages, PAGE))
+    args = (_placed(one_chip, params), _placed(one_chip, pool),
+            _sds(one_chip, (B, 1), jnp.int32),
+            _sds(one_chip, (B,), jnp.int32),
+            _sds(one_chip, (B, L // PAGE), jnp.int32))
+    compiled = jax.jit(make_paged_serve_step(model)).lower(*args).compile()
+    assert compiled.memory_analysis().argument_size_in_bytes < 2 * 2 ** 30
