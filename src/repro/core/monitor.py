@@ -44,7 +44,8 @@ class Monitor:
         self._step_times: Dict[str, List[float]] = {}
         self._straggler_strikes: Dict[str, int] = {}
         self._pages: Dict[str, Tuple[int, int]] = {}   # dev -> (used, total)
-        self._scrub: Dict[str, Tuple[int, float]] = {}  # dev -> (pages, ms)
+        # dev -> (pages scrubbed, scrub dispatches)
+        self._scrub: Dict[str, Tuple[int, int]] = {}
         # (t, arrivals, completions, active_devices) per fleet round, t on
         # the injected clock (event time under the event-driven loop)
         self._traffic: List[Tuple[float, int, int, int]] = []
@@ -209,12 +210,13 @@ class Monitor:
         and ``status()`` read it; clearing happens when an engine parks."""
         self._pages[device_id] = (int(used), int(total))
 
-    def record_scrub(self, device_id: str, pages: int, ms: float):
-        """Cumulative zero-on-free cost for one device's pool (pushed
-        alongside ``record_pages``): how many freed pages were scrubbed
-        and how many milliseconds the batched scrub dispatches cost. The
-        operator's view of what the isolation policy is buying/costing."""
-        self._scrub[device_id] = (int(pages), float(ms))
+    def record_scrub(self, device_id: str, pages: int, dispatches: int):
+        """Cumulative zero-on-free work for one device's pool (pushed
+        alongside ``record_pages``): how many freed pages were scrubbed,
+        in how many batched scrub dispatches. The time they cost is in a
+        profiler trace: the host's ``rc3e.engine.scrub`` spans and the
+        device's ``_scrub_pool_pages`` executions."""
+        self._scrub[device_id] = (int(pages), int(dispatches))
 
     def clear_pages(self, device_id: str):
         self._pages.pop(device_id, None)
@@ -249,8 +251,8 @@ class Monitor:
             "pages": {dev: {"used": used, "total": total,
                             "occupancy": round(used / max(1, total), 4)}
                       for dev, (used, total) in self._pages.items()},
-            "scrub": {dev: {"pages": pages, "ms": round(ms, 3)}
-                      for dev, (pages, ms) in self._scrub.items()},
+            "scrub": {dev: {"pages": pages, "dispatches": n}
+                      for dev, (pages, n) in self._scrub.items()},
             "page_grants": self.db.page_grants(),
             "median_step_ms": self.median_step_ms(),
             "traffic": self.traffic_stats(),

@@ -405,17 +405,18 @@ def attn_decode_paged(p, x, positions, cache, block_tables, opts: AttnOpts):
     # (kernels/decode_attention.py) sweeps a pool in place on TPU but
     # consumes the (P, Hkv, ps, D) layout — wiring it in here requires
     # transposing this pool's (P, ps, kv, hd) leaves (axes 1<->2)
-    if quant:
-        k_all = _deq(cache["k"][block_tables],
-                     cache["k_scale"][block_tables], x.dtype)
-        v_all = _deq(cache["v"][block_tables],
-                     cache["v_scale"][block_tables], x.dtype)
-    else:
-        k_all = cache["k"][block_tables]         # (B, nb, ps, kv, hd)
-        v_all = cache["v"][block_tables]
-    k_all = k_all.reshape((B, -1) + k_all.shape[3:])
-    v_all = v_all.reshape((B, -1) + v_all.shape[3:])
-    kpos = cache["pos"][block_tables].reshape(B, -1)
+    with jax.named_scope("rc3e.attn.gather"):
+        if quant:
+            k_all = _deq(cache["k"][block_tables],
+                         cache["k_scale"][block_tables], x.dtype)
+            v_all = _deq(cache["v"][block_tables],
+                         cache["v_scale"][block_tables], x.dtype)
+        else:
+            k_all = cache["k"][block_tables]     # (B, nb, ps, kv, hd)
+            v_all = cache["v"][block_tables]
+        k_all = k_all.reshape((B, -1) + k_all.shape[3:])
+        v_all = v_all.reshape((B, -1) + v_all.shape[3:])
+        kpos = cache["pos"][block_tables].reshape(B, -1)
     mask = _causal_mask(positions, kpos, opts.window, opts.causal,
                         k_valid=kpos >= 0)
     y = _attend(q, k_all, v_all, mask, opts)

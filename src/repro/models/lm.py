@@ -52,10 +52,11 @@ def init_lm(cfg: ModelConfig, key) -> dict:
 
 
 def _embed_tokens(cfg, params, tokens, patches=None):
-    x = embed(params["embed"], tokens, scale_by_dim=cfg.embed_scale)
-    x = x.astype(_dtype(cfg))
-    if patches is not None:
-        x = jnp.concatenate([patches.astype(x.dtype), x], axis=1)
+    with jax.named_scope("rc3e.embed"):
+        x = embed(params["embed"], tokens, scale_by_dim=cfg.embed_scale)
+        x = x.astype(_dtype(cfg))
+        if patches is not None:
+            x = jnp.concatenate([patches.astype(x.dtype), x], axis=1)
     return x
 
 
@@ -91,8 +92,16 @@ def lm_prefill(cfg: ModelConfig, params, tokens, max_len: int, patches=None,
     x, caches, _ = apply_stages(cfg, params, x, pos, mode="prefill",
                                 max_len=max_len, cache_dtype=_dtype(cfg),
                                 clamp_window=clamp_window)
-    h = rms_norm(x, params["final_norm"])
+    with jax.named_scope("rc3e.head"):
+        h = rms_norm(x, params["final_norm"])
     return h, caches
+
+
+def _head(cfg: ModelConfig, params, x):
+    """Final norm and logits of a decode step."""
+    with jax.named_scope("rc3e.head"):
+        h = rms_norm(x, params["final_norm"])
+        return lm_logits(cfg, params, h)
 
 
 def lm_decode(cfg: ModelConfig, params, caches, tokens, pos):
@@ -101,8 +110,7 @@ def lm_decode(cfg: ModelConfig, params, caches, tokens, pos):
     positions = pos[:, None].astype(jnp.int32)
     x, caches, _ = apply_stages(cfg, params, x, positions, mode="decode",
                                 caches=caches)
-    h = rms_norm(x, params["final_norm"])
-    return lm_logits(cfg, params, h), caches
+    return _head(cfg, params, x), caches
 
 
 def lm_decode_paged(cfg: ModelConfig, params, caches, tokens, pos,
@@ -114,8 +122,7 @@ def lm_decode_paged(cfg: ModelConfig, params, caches, tokens, pos,
     positions = pos[:, None].astype(jnp.int32)
     x, caches, _ = apply_stages(cfg, params, x, positions, mode="decode",
                                 caches=caches, block_tables=block_tables)
-    h = rms_norm(x, params["final_norm"])
-    return lm_logits(cfg, params, h), caches
+    return _head(cfg, params, x), caches
 
 
 def make_decode_caches(cfg: ModelConfig, batch: int, max_len: int):

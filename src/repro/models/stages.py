@@ -269,6 +269,16 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int, dtype):
 # Site application
 # ---------------------------------------------------------------------------
 
+def _mlp(cfg, site, pp, h, x, aux):
+    """A site's feed-forward half: (y, aux)."""
+    with jax.named_scope("rc3e.mlp"):
+        if site.mlp == "dense":
+            return mlp_forward(pp["mlp"], h, cfg.act), aux
+        if site.mlp == "moe":
+            return moe_forward(pp["moe"], h, moe_opts(cfg))
+        return jnp.zeros_like(x), aux
+
+
 def _apply_site_full(cfg, site, p, shared, x, positions, mode, max_len, dtype,
                      clamp_window: bool = True):
     """Full-sequence site application.
@@ -290,23 +300,19 @@ def _apply_site_full(cfg, site, p, shared, x, positions, mode, max_len, dtype,
 
     pp = shared if site.mixer == MIXER_SHARED_ATTN else p
     h = rms_norm(x, pp["norm1"])
-    if cfg.mla is not None:
-        y, (c_kv, k_rope) = mla_forward(pp["attn"], h, positions,
-                                        mla_opts(cfg))
-    else:
-        y, (k, v) = attn_forward(pp["attn"], h, positions,
-                                 attn_opts(cfg, site))
+    with jax.named_scope("rc3e.attn"):
+        if cfg.mla is not None:
+            y, (c_kv, k_rope) = mla_forward(pp["attn"], h, positions,
+                                            mla_opts(cfg))
+        else:
+            y, (k, v) = attn_forward(pp["attn"], h, positions,
+                                     attn_opts(cfg, site))
     if cfg.post_norm:
         y = rms_norm(y, p["norm1_post"])
     x = x + y
 
     h = rms_norm(x, pp["norm2"])
-    if site.mlp == "dense":
-        y = mlp_forward(pp["mlp"], h, cfg.act)
-    elif site.mlp == "moe":
-        y, aux = moe_forward(pp["moe"], h, moe_opts(cfg))
-    else:
-        y = jnp.zeros_like(x)
+    y, aux = _mlp(cfg, site, pp, h, x, aux)
     if cfg.post_norm:
         y = rms_norm(y, p["norm2_post"])
     x = x + y
@@ -332,18 +338,14 @@ def _apply_site_decode_paged(cfg, site, p, shared, x, positions, cache,
     aux = jnp.zeros((), jnp.float32)
     pp = shared if site.mixer == MIXER_SHARED_ATTN else p
     h = rms_norm(x, pp["norm1"])
-    y, cache = attn_decode_paged(pp["attn"], h, positions, cache,
-                                 block_tables, attn_opts(cfg, site))
+    with jax.named_scope("rc3e.attn"):
+        y, cache = attn_decode_paged(pp["attn"], h, positions, cache,
+                                     block_tables, attn_opts(cfg, site))
     if cfg.post_norm:
         y = rms_norm(y, p["norm1_post"])
     x = x + y
     h = rms_norm(x, pp["norm2"])
-    if site.mlp == "dense":
-        y = mlp_forward(pp["mlp"], h, cfg.act)
-    elif site.mlp == "moe":
-        y, aux = moe_forward(pp["moe"], h, moe_opts(cfg))
-    else:
-        y = jnp.zeros_like(x)
+    y, aux = _mlp(cfg, site, pp, h, x, aux)
     if cfg.post_norm:
         y = rms_norm(y, p["norm2_post"])
     return x + y, cache, aux
@@ -358,21 +360,18 @@ def _apply_site_decode(cfg, site, p, shared, x, positions, cache):
 
     pp = shared if site.mixer == MIXER_SHARED_ATTN else p
     h = rms_norm(x, pp["norm1"])
-    if cfg.mla is not None:
-        y, cache = mla_decode(pp["attn"], h, positions, cache, mla_opts(cfg))
-    else:
-        y, cache = attn_decode(pp["attn"], h, positions, cache,
-                               attn_opts(cfg, site))
+    with jax.named_scope("rc3e.attn"):
+        if cfg.mla is not None:
+            y, cache = mla_decode(pp["attn"], h, positions, cache,
+                                  mla_opts(cfg))
+        else:
+            y, cache = attn_decode(pp["attn"], h, positions, cache,
+                                   attn_opts(cfg, site))
     if cfg.post_norm:
         y = rms_norm(y, p["norm1_post"])
     x = x + y
     h = rms_norm(x, pp["norm2"])
-    if site.mlp == "dense":
-        y = mlp_forward(pp["mlp"], h, cfg.act)
-    elif site.mlp == "moe":
-        y, aux = moe_forward(pp["moe"], h, moe_opts(cfg))
-    else:
-        y = jnp.zeros_like(x)
+    y, aux = _mlp(cfg, site, pp, h, x, aux)
     if cfg.post_norm:
         y = rms_norm(y, p["norm2_post"])
     return x + y, cache, aux

@@ -47,6 +47,7 @@ from repro.configs.base import GeometryConfig
 from repro.core.device_db import DeviceState, SliceState
 from repro.core.elastic import ElasticController
 from repro.core.hypervisor import Hypervisor
+from repro.core.spans import span
 from repro.models.api import Model
 from repro.runtime.faults import FaultInjector
 from repro.runtime.gateway import (TenantSession, settle_finished_request,
@@ -586,16 +587,18 @@ class GatewayFleet:
         if eng is None or not self._device_alive(dev):
             return 0
         t0 = time.monotonic()
-        n = eng.step() if prefill_chunk is None \
-            else eng.step_async(prefill_chunk)
+        with span("rc3e.fleet.engine_step", device=dev, chip=eng.device.id):
+            n = eng.step() if prefill_chunk is None \
+                else eng.step_async(prefill_chunk)
         if n:
             self.last_round_ms[dev] = (time.monotonic() - t0) * 1e3
-        self._sync_journal(eng)
-        if eng.paged:
-            self.hv.monitor.record_pages(dev, eng.pool.used_pages,
-                                         eng.pool.total_pages)
-            self.hv.monitor.record_scrub(dev, eng.pool.pages_scrubbed,
-                                         eng.scrub_ms)
+        with span("rc3e.fleet.journal_sync", device=dev):
+            self._sync_journal(eng)
+            if eng.paged:
+                self.hv.monitor.record_pages(dev, eng.pool.used_pages,
+                                             eng.pool.total_pages)
+                self.hv.monitor.record_scrub(dev, eng.pool.pages_scrubbed,
+                                             eng.scrub_dispatches)
         return n
 
     def finish_round(self) -> None:
@@ -625,12 +628,15 @@ class GatewayFleet:
         event-driven loop (``runtime.events.EventLoop``) composes the same
         three pieces but schedules ``step_engine`` per device on its own
         event-time cadence — no fleet-wide barrier."""
-        self.begin_round()
-        total = 0
-        self.last_round_ms = {}
-        for dev in list(self._engines):
-            total += self.step_engine(dev)
-        self.finish_round()
+        with span("rc3e.fleet.round"):
+            with span("rc3e.fleet.begin_round"):
+                self.begin_round()
+            total = 0
+            self.last_round_ms = {}
+            for dev in list(self._engines):
+                total += self.step_engine(dev)
+            with span("rc3e.fleet.finish_round"):
+                self.finish_round()
         return total
 
     def run_until_idle(self, max_steps: int = 10000) -> bool:

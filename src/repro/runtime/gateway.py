@@ -248,7 +248,7 @@ class ServingGateway:
                                          self.engine.pool.total_pages)
             self.hv.monitor.record_scrub(self._device_key,
                                          self.engine.pool.pages_scrubbed,
-                                         self.engine.scrub_ms)
+                                         self.engine.scrub_dispatches)
         if self.migrate_every and self.engine.steps \
                 and self.engine.steps % self.migrate_every == 0:
             self.rebalance()
@@ -266,7 +266,7 @@ class ServingGateway:
                                          self.engine.pool.total_pages)
             self.hv.monitor.record_scrub(self._device_key,
                                          self.engine.pool.pages_scrubbed,
-                                         self.engine.scrub_ms)
+                                         self.engine.scrub_dispatches)
         if self.migrate_every and self.engine.steps \
                 and self.engine.steps % self.migrate_every == 0:
             self.rebalance()

@@ -21,6 +21,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.analysis.lifecycle import sanitizer
 from repro.configs.base import ModelConfig
+from repro.core.spans import span
 from repro.models.api import Model
 from repro.runtime.paged import PagePoolManager, default_pool_pages
 from repro.runtime.sharding import (batch_specs, cache_specs, dp_axes, named,
@@ -173,7 +174,8 @@ def _argmax_tokens(logits):
     numpy — a vocab-sized D2H transfer per decode step (n_slots * vocab *
     4 bytes, ~0.5 MB at vocab 32k / 4 slots) for 4 bytes of answer per
     slot."""
-    return jnp.argmax(logits[:, 0, :], axis=-1).astype(jnp.int32)
+    with jax.named_scope("rc3e.argmax"):
+        return jnp.argmax(logits[:, 0, :], axis=-1).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -194,6 +196,7 @@ class Request:
     out_tokens: List[int] = dataclasses.field(default_factory=list)
     done: threading.Event = dataclasses.field(default_factory=threading.Event)
     submitted_at: float = dataclasses.field(default_factory=time.monotonic)
+    admitted_at: Optional[float] = None   # first admission into a slot
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
     finish_reason: Optional[str] = None   # "eos" | "length" | "cancelled"
@@ -309,7 +312,7 @@ class BatchingEngine:
         self._prefilling: Dict[int, _PendingPrefill] = {}
         self.steps = 0
         self.preemptions = 0
-        self.scrub_ms = 0.0        # cumulative zero-on-free dispatch cost
+        self.scrub_dispatches = 0  # batched zero-on-free scrub calls
         self._scope = sanitizer.scope()      # slot-machine key namespace
         # device block-table cache, keyed on the pool's version counter:
         # steady-state decode steps reuse it instead of re-uploading the
@@ -600,12 +603,12 @@ class BatchingEngine:
         if not self.paged or not self.pool.scrub_pending:
             return 0
         pids = self.pool.take_scrub()
-        t0 = time.monotonic()
-        self.caches = _scrub_pool_pages(
-            self.caches,
-            self._put(np.asarray(sorted(pids),       # rc3e: allow-host-sync
-                                 np.int32)))
-        self.scrub_ms += (time.monotonic() - t0) * 1e3
+        with span("rc3e.engine.scrub", pages=len(pids)):
+            self.caches = _scrub_pool_pages(
+                self.caches,
+                self._put(np.asarray(sorted(pids),   # rc3e: allow-host-sync
+                                     np.int32)))
+        self.scrub_dispatches += 1
         return len(pids)
 
     def _page_budget_ok(self, tenant: str, extra: int) -> bool:
@@ -689,35 +692,44 @@ class BatchingEngine:
         for slot in range(self.n_slots):
             if self._slots[slot] is not None:
                 continue
-            req = self._pop_next_request()
+            with span("rc3e.engine.pick"):
+                req = self._pop_next_request()
             if req is None:
                 return
-            self._slots[slot] = req
-            sanitizer.emit("slot", (self._scope, slot), "occupy")
-            _req_event(req, "admit")
-            if async_chunk is not None:
-                # event-driven admission: buffer the prefill and account
-                # it async_chunk tokens per engine event (see step_async)
-                self._start_prefill_async(slot, req, async_chunk)
-                continue
-            # a request resumed after live migration replays prompt +
-            # already-generated tokens so decode continues where it left off
-            toks = self._ctx_tokens(req)
-            if self.paged:
-                self._admit_paged(slot, req, toks)
+            if req.admitted_at is None:
+                req.admitted_at = time.monotonic()
+            with span("rc3e.engine.admit", request=req.request_id):
+                self._admit_one(slot, req, async_chunk)
+
+    def _admit_one(self, slot: int, req: Request,
+                   async_chunk: Optional[int]):
+        """Seat ``req`` in the free ``slot`` and prefill its context."""
+        self._slots[slot] = req
+        sanitizer.emit("slot", (self._scope, slot), "occupy")
+        _req_event(req, "admit")
+        if async_chunk is not None:
+            # event-driven admission: buffer the prefill and account it
+            # async_chunk tokens per engine event (see step_async)
+            self._start_prefill_async(slot, req, async_chunk)
+            return
+        # a request resumed after live migration replays prompt +
+        # already-generated tokens so decode continues where it left off
+        toks = self._ctx_tokens(req)
+        if self.paged:
+            self._admit_paged(slot, req, toks)
+        else:
+            ctx = toks[:-1]
+            if len(ctx) >= self.PREFILL_MIN_TOKENS \
+                    and self.prefill_mode == "batched":
+                self._prefill_slot(slot, req, ctx)
             else:
-                ctx = toks[:-1]
-                if len(ctx) >= self.PREFILL_MIN_TOKENS \
-                        and self.prefill_mode == "batched":
-                    self._prefill_slot(slot, ctx)
-                else:
-                    # short context (or legacy mode): feed tokens through
-                    # the already-compiled decode program, slot-isolated
-                    for i, t in enumerate(ctx):
-                        self._step_single(slot, int(t), i)
-                self._pos[slot] = len(toks) - 1
-            req._next_input = int(toks[-1])
-            _req_event(req, "ready")   # lockstep: prefill completed inline
+                # short context (or legacy mode): feed tokens through the
+                # already-compiled decode program, slot-isolated
+                for i, t in enumerate(ctx):
+                    self._step_single(slot, int(t), i)
+            self._pos[slot] = len(toks) - 1
+        req._next_input = int(toks[-1])
+        _req_event(req, "ready")   # lockstep: prefill completed inline
 
     def _start_prefill_async(self, slot: int, req: Request, chunk: int):
         """Admit ``req`` into ``slot`` without blocking the engine event:
@@ -740,7 +752,7 @@ class BatchingEngine:
             pass                        # every context page prefix-matched
         elif len(ctx) >= self.PREFILL_MIN_TOKENS \
                 and self.prefill_mode == "batched":
-            _, buf = self._prefill(self.params, self._pad_ctx(ctx))
+            buf = self._run_prefill(req, ctx)
             chunks = -(-len(ctx) // max(1, int(chunk)))   # ceil
         else:
             if plan is not None:
@@ -763,14 +775,11 @@ class BatchingEngine:
         req = self._slots[slot]
         if pending.buf is not None:
             if self.paged:
-                plan = pending.plan
-                pages = self._put(
-                    np.asarray(plan.write_pages,     # rc3e: allow-host-sync
-                               np.int32))
-                self.caches = _splice_pages(self.caches, pending.buf, pages,
-                                            start=plan.write_start)
+                self._splice_paged(pending.buf, pending.plan)
             else:
-                self.caches = self._splice(self.caches, pending.buf, slot)
+                with span("rc3e.engine.splice"):
+                    self.caches = self._splice(self.caches, pending.buf,
+                                               slot)
         self._pos[slot] = pending.ctx_len - 1
         req._next_input = pending.last_token
         _req_event(req, "ready")
@@ -787,35 +796,50 @@ class BatchingEngine:
         if not plan.skip_prefill:
             if len(ctx) >= self.PREFILL_MIN_TOKENS \
                     and self.prefill_mode == "batched":
-                self._prefill_slot_paged(slot, ctx, plan)
+                self._prefill_slot_paged(slot, req, ctx, plan)
             else:
                 self._invalidate_pages(plan.write_pages)
                 for i, t in enumerate(ctx):
                     self._step_single(slot, int(t), i)
         self._pos[slot] = len(toks) - 1
 
-    def _prefill_slot(self, slot: int, ctx: np.ndarray):
+    def _prefill_slot(self, slot: int, req: Request, ctx: np.ndarray):
         """Prefill a slot's context with ONE batched call instead of one
         full-batch decode per prompt token (O(S·n_slots) -> O(S) work,
         O(1) dispatches). Lengths are padded to power-of-two buckets to
         bound recompiles; padded positions carry pos >= len(ctx), so they
         are causally masked during decode and overwritten in place when
         generation reaches them."""
-        _, slot_caches = self._prefill(self.params,
-                                       self._pad_ctx(ctx))
-        self.caches = self._splice(self.caches, slot_caches, slot)
+        slot_caches = self._run_prefill(req, ctx)
+        with span("rc3e.engine.splice"):
+            self.caches = self._splice(self.caches, slot_caches, slot)
 
-    def _prefill_slot_paged(self, slot: int, ctx: np.ndarray, plan):
+    def _prefill_slot_paged(self, slot: int, req: Request, ctx: np.ndarray,
+                            plan):
         """Prefill, then scatter ONLY the unshared suffix blocks into this
         slot's pool pages (shared prefix pages already hold identical
         content — that's the point of sharing them)."""
-        _, slot_caches = self._prefill(self.params, self._pad_ctx(ctx))
-        # admission-time upload of the write-page index vector
-        pages = self._put(
-            np.asarray(plan.write_pages,             # rc3e: allow-host-sync
-                       np.int32))
-        self.caches = _splice_pages(self.caches, slot_caches, pages,
-                                    start=plan.write_start)
+        self._splice_paged(self._run_prefill(req, ctx), plan)
+
+    def _run_prefill(self, req: Request, ctx: np.ndarray):
+        """Dispatch the batched prefill of ``ctx``; returns its batch-1
+        caches. The span's ``tokens`` are the real context tokens,
+        ``padded`` the bucket ``_pad_ctx`` chose."""
+        toks = self._pad_ctx(ctx)
+        with span("rc3e.engine.prefill", request=req.request_id,
+                  tokens=len(ctx), padded=toks.shape[1]):
+            _, slot_caches = self._prefill(self.params, toks)
+        return slot_caches
+
+    def _splice_paged(self, slot_caches, plan) -> None:
+        """Scatter a batch-1 prefill into the plan's write pages."""
+        with span("rc3e.engine.splice"):
+            # admission-time upload of the write-page index vector
+            pages = self._put(
+                np.asarray(plan.write_pages,         # rc3e: allow-host-sync
+                           np.int32))
+            self.caches = _splice_pages(self.caches, slot_caches, pages,
+                                        start=plan.write_start)
 
     def _pad_ctx(self, ctx: np.ndarray):
         n = len(ctx)
@@ -860,13 +884,15 @@ class BatchingEngine:
                 self.params, self.caches, self._put(tokens),
                 self._put(posv))
 
-    def _prepare_writes(self):
+    def _prepare_writes(self) -> Dict[str, int]:
         """Before a paged decode step: every active slot's write position
         must land in a privately owned page. Crossing a page boundary
         grows the slot by one page; a shared (prefix) page is detached
         copy-on-write; exhaustion preempts the slot back to its queue head
-        (generated tokens survive via prefix replay)."""
+        (generated tokens survive via prefix replay). Returns how many
+        slots this sweep grew, detached and preempted."""
         ps = self.page_size
+        n = {"grown": 0, "cow": 0, "preempted": 0}
         for i, req in enumerate(self._slots):
             if req is None or i in self._prefilling:
                 continue            # mid-prefill: pos is -1, nothing writes
@@ -880,8 +906,10 @@ class BatchingEngine:
                     # can be regrown here
                     self._flush_scrub()
                     self._invalidate_pages([self.pool.grow(i, req.tenant)])
+                    n["grown"] += 1
                 else:
                     self._preempt(i)
+                    n["preempted"] += 1
                 continue
             if self.pool.is_shared(i, block):
                 if self.pool.free_pages >= 1 and \
@@ -890,10 +918,13 @@ class BatchingEngine:
                     src, dst = self.pool.cow(i, block, req.tenant)
                     self.caches = _copy_page(self.caches, np.int32(src),
                                              np.int32(dst))
+                    n["cow"] += 1
                 else:
                     self._preempt(i)
+                    n["preempted"] += 1
                 continue
             self.pool.touch_write(i, block)
+        return n
 
     def _preempt(self, slot: int):
         req = self._slots[slot]
@@ -933,47 +964,51 @@ class BatchingEngine:
         """One decode step over every ready slot (mid-prefill slots are
         excluded). Returns the number of slots decoded."""
         if self.paged:
-            self._prepare_writes()
+            with span("rc3e.engine.prepare_writes") as sp:
+                sp.set(**self._prepare_writes())
         active = [i for i, r in enumerate(self._slots)
                   if r is not None and i not in self._prefilling]
         if not active:
             return 0
-        tokens = np.zeros((self.n_slots, 1), np.int32)
-        for i in active:
-            tokens[i, 0] = self._slots[i]._next_input
-        t0 = time.monotonic()
-        # the two small per-step uploads ((n_slots, 1) tokens and
-        # (n_slots,) positions) are the step's inputs — unavoidable and
-        # tiny; the block tables are served from the version-keyed cache
-        if self.paged:
-            logits, self.caches = self._decode(
-                self.params, self.caches, self._put(tokens),
-                self._put(self._pos), self._block_tables_dev())
-        else:
-            logits, self.caches = self._decode(
-                self.params, self.caches, self._put(tokens),
-                self._put(self._pos))
-        # argmax on device: fetch (n_slots,) int32 ids, not the full
-        # (n_slots, 1, vocab) logits tensor
-        next_ids = np.asarray(                       # rc3e: allow-host-sync
-            _argmax_tokens(logits))
-        step_ms = (time.monotonic() - t0) * 1e3
+        with span("rc3e.engine.decode_dispatch"):
+            tokens = np.zeros((self.n_slots, 1), np.int32)
+            for i in active:
+                tokens[i, 0] = self._slots[i]._next_input
+            t0 = time.monotonic()
+            # the two small per-step uploads ((n_slots, 1) tokens and
+            # (n_slots,) positions) are the step's inputs — unavoidable
+            # and tiny; the block tables come from the version-keyed cache
+            if self.paged:
+                logits, self.caches = self._decode(
+                    self.params, self.caches, self._put(tokens),
+                    self._put(self._pos), self._block_tables_dev())
+            else:
+                logits, self.caches = self._decode(
+                    self.params, self.caches, self._put(tokens),
+                    self._put(self._pos))
+            # argmax on device: fetch (n_slots,) int32 ids, not the full
+            # (n_slots, 1, vocab) logits tensor
+            ids = _argmax_tokens(logits)
+        with span("rc3e.engine.readback"):
+            next_ids = np.asarray(ids)               # rc3e: allow-host-sync
+            step_ms = (time.monotonic() - t0) * 1e3
         self.steps += 1
-        if self.on_step is not None:
-            self.on_step(self.active_by_tenant(), step_ms)
-        for i in active:
-            req = self._slots[i]
-            nxt = int(next_ids[i])
-            if req.first_token_at is None:
-                req.first_token_at = time.monotonic()
-            req.out_tokens.append(nxt)
-            req._next_input = nxt
-            self._pos[i] += 1
-            eos = self.eos_id is not None and nxt == self.eos_id
-            if len(req.out_tokens) >= req.max_new_tokens or eos \
-                    or self._pos[i] >= self.max_len - 1:
-                self._release_slot(i)
-                self._finish(req, "eos" if eos else "length")
+        with span("rc3e.engine.emit"):
+            if self.on_step is not None:
+                self.on_step(self.active_by_tenant(), step_ms)
+            for i in active:
+                req = self._slots[i]
+                nxt = int(next_ids[i])
+                if req.first_token_at is None:
+                    req.first_token_at = time.monotonic()
+                req.out_tokens.append(nxt)
+                req._next_input = nxt
+                self._pos[i] += 1
+                eos = self.eos_id is not None and nxt == self.eos_id
+                if len(req.out_tokens) >= req.max_new_tokens or eos \
+                        or self._pos[i] >= self.max_len - 1:
+                    self._release_slot(i)
+                    self._finish(req, "eos" if eos else "length")
         return len(active)
 
     def idle(self) -> bool:
@@ -1002,7 +1037,7 @@ class BatchingEngine:
             return {}
         s = self.pool.stats()
         s["preemptions"] = self.preemptions
-        s["scrub_ms"] = round(self.scrub_ms, 3)
+        s["scrub_dispatches"] = self.scrub_dispatches
         return s
 
     def export_request_pages(self, req: Request):
