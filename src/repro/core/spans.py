@@ -91,6 +91,11 @@ class _Null:
     def set(self, **attrs) -> None:
         pass
 
+    def __bool__(self) -> bool:
+        """False, so that attributes that take work to count are counted
+        only under a session: ``if sp: sp.set(...)``."""
+        return False
+
 
 _NULL = _Null()
 

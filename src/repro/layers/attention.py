@@ -18,6 +18,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels import registry as kreg
 from repro.layers.norms import rms_norm, softcap
@@ -400,28 +401,123 @@ def attn_decode_paged(p, x, positions, cache, block_tables, opts: AttnOpts):
         new["v"] = cache["v"].at[pid, off].set(v[:, 0])
     new["pos"] = cache["pos"].at[pid, off].set(jnp.where(active, pos, -1))
     cache = new
-    # gather this batch's pages into the (B, L, kv, hd) view the score
-    # einsum expects (L = nb * ps). The Pallas paged kernel
-    # (kernels/decode_attention.py) sweeps a pool in place on TPU but
-    # consumes the (P, Hkv, ps, D) layout — wiring it in here requires
-    # transposing this pool's (P, ps, kv, hd) leaves (axes 1<->2)
-    with jax.named_scope("rc3e.attn.gather"):
-        if quant:
-            k_all = _deq(cache["k"][block_tables],
-                         cache["k_scale"][block_tables], x.dtype)
-            v_all = _deq(cache["v"][block_tables],
-                         cache["v_scale"][block_tables], x.dtype)
-        else:
-            k_all = cache["k"][block_tables]     # (B, nb, ps, kv, hd)
-            v_all = cache["v"][block_tables]
-        k_all = k_all.reshape((B, -1) + k_all.shape[3:])
-        v_all = v_all.reshape((B, -1) + v_all.shape[3:])
-        kpos = cache["pos"][block_tables].reshape(B, -1)
-    mask = _causal_mask(positions, kpos, opts.window, opts.causal,
-                        k_valid=kpos >= 0)
-    y = _attend(q, k_all, v_all, mask, opts)
+    nb = block_tables.shape[1]
+    chunk = sweep_chunk_cols(B, nb, ps, opts.n_kv_heads, opts.head_dim,
+                             cache["k"].dtype.itemsize)
+    y = _paged_sweep(q, cache, block_tables, positions, opts, chunk)
     out = jnp.einsum("bshgk,hgkd->bsd", y, p["wo"].astype(x.dtype))
     return out, cache
+
+
+# K and V bytes that one chunk of the paged decode sweep gathers for the
+# whole batch. A block table whose K and V fit in this is swept in one
+# pass (smollm-135m's at 4 slots of 2048 positions: 6.3 MB). phi3-mini's
+# (4 slots, 4096 positions, 32 heads of 96) goes in chunks of 256
+# positions, the fastest of 256, 512 and 1024 on a TPU v5e: a decode step
+# of rows at 1100-2100 positions took 36.5, 39.0 and 41.6 ms.
+SWEEP_CHUNK_BYTES = 12 << 20
+
+
+def sweep_chunk_cols(batch: int, n_cols: int, page_size: int, n_kv_heads: int,
+                     head_dim: int, itemsize: int) -> int:
+    """Block-table columns in one chunk of the paged decode sweep: as many
+    as hold ``SWEEP_CHUNK_BYTES`` of the batch's K and V, or the whole
+    table when it holds less."""
+    per_col = 2 * batch * page_size * n_kv_heads * head_dim * itemsize
+    return max(1, min(n_cols, SWEEP_CHUNK_BYTES // per_col))
+
+
+def sweep_chunks(pos, page_size: int, n_cols: int, window: int, chunk: int,
+                 xp=jnp):
+    """Chunks ``[lo, hi)`` of ``chunk`` block-table columns that the paged
+    decode sweep covers for positions ``pos`` (B,), -1 for an inactive
+    row: from the chunk holding the first position any active row's
+    window reaches to the one holding the furthest active position. A
+    table of one chunk is swept whole. Pure over ``xp``: ``jnp`` on the
+    device, ``numpy`` for the engine's count of what the device swept."""
+    if chunk >= n_cols:
+        return 0, 1
+    active = pos >= 0
+    hi = xp.max(xp.where(active, pos, -1)) // page_size // chunk + 1
+    if not window:
+        return 0, hi
+    first = xp.min(xp.where(active, xp.maximum(pos - window + 1, 0),
+                            n_cols * page_size))
+    return xp.minimum(first // page_size // chunk, hi), hi
+
+
+def swept_cols(pos, page_size: int, n_cols: int, window: int,
+               chunk: int) -> int:
+    """Block-table columns the paged decode sweep covers for host
+    positions ``pos`` (a numpy array)."""
+    lo, hi = sweep_chunks(pos, page_size, n_cols, window, chunk, xp=np)
+    return max(0, min(int(hi) * chunk, n_cols) - int(lo) * chunk)
+
+
+def _paged_sweep(q, cache, block_tables, positions, opts: AttnOpts,
+                 chunk: int):
+    """Attention of the decode queries q (B,1,kv,g,hd) over the pages the
+    batch can see: ``chunk`` block-table columns at a time, over the
+    chunks ``sweep_chunks`` bounds on the device, with an online softmax
+    (running max, running sum, f32 accumulator) across chunks. K and V
+    enter both dots in their stored dtype with f32 accumulation, so no
+    f32 copy of the gathered pages is made; an int8 pool is dequantised
+    chunk by chunk. Unused table entries point at the null page (pos -1),
+    which the mask drops, as do positions past a row's own or outside its
+    window.
+
+    The Pallas paged kernel (kernels/decode_attention.py) is not used
+    here: it takes one grid step per (row, head, page), 32768 a phi3-mini
+    layer, and reads a (P, kv, ps, hd) pool, not this (P, ps, kv, hd) one."""
+    B, _, kv, g, hd = q.shape
+    nb = block_tables.shape[1]
+    lo, hi = sweep_chunks(positions[:, 0], cache["k"].shape[1], nb,
+                          opts.window, chunk)
+    bt = jnp.pad(block_tables, ((0, 0), (0, -nb % chunk)))
+    # With one query head per KV head both dots are vector-matrix
+    # products, which XLA rewrites as f32 multiply-reduces over an f32
+    # copy of the gathered pages. A second, zero query row keeps them on
+    # the MXU in the pool's dtype; its scores and outputs are dropped.
+    extra = 1 if g == 1 else 0
+    qr = jnp.pad(q, ((0, 0), (0, extra), (0, 0), (0, 0), (0, 0)))
+
+    def update(c, carry):
+        m, l, acc = carry
+        cols = jax.lax.dynamic_slice_in_dim(bt, c * chunk, chunk, axis=1)
+        with jax.named_scope("rc3e.attn.gather"):
+            k, v = cache["k"][cols], cache["v"][cols]    # (B, C, ps, kv, hd)
+            if "k_scale" in cache:
+                k = _deq(k, cache["k_scale"][cols], q.dtype)
+                v = _deq(v, cache["v_scale"][cols], q.dtype)
+            kpos = cache["pos"][cols].reshape(B, -1)
+        k = k.reshape(B, -1, kv, hd)
+        v = v.reshape(B, -1, kv, hd)
+        s = jnp.einsum("bqhgc,bshc->bhgqs", qr, k,
+                       preferred_element_type=jnp.float32)[..., :1, :]
+        s = softcap(s, opts.softcap)
+        mask = _causal_mask(positions, kpos, opts.window, opts.causal,
+                            k_valid=kpos >= 0)[:, None, None]
+        s = jnp.where(mask, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        pr = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(pr, axis=-1, keepdims=True)
+        pr = jnp.pad(pr.astype(v.dtype),
+                     ((0, 0), (0, 0), (0, 0), (0, extra), (0, 0)))
+        pv = jnp.einsum("bhgqs,bshc->bhgqc", pr, v,
+                        preferred_element_type=jnp.float32)[..., :1, :]
+        return m_new, l, acc * alpha + pv
+
+    carry = (jnp.full((B, kv, g, 1, 1), NEG_INF, jnp.float32),
+             jnp.zeros((B, kv, g, 1, 1), jnp.float32),
+             jnp.zeros((B, kv, g, 1, hd), jnp.float32))
+    if chunk >= nb:
+        carry = update(0, carry)
+    else:
+        carry = jax.lax.fori_loop(lo, hi, update, carry)
+    _, l, acc = carry
+    y = acc / jnp.maximum(l, 1e-30)                       # (B, kv, g, 1, hd)
+    return jnp.moveaxis(y, 3, 1).astype(q.dtype)
 
 
 def attn_decode(p, x, positions, cache, opts: AttnOpts, update_cache=True):

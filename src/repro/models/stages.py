@@ -23,7 +23,7 @@ from repro.configs.base import (ATTN_GLOBAL, ATTN_LOCAL, MIXER_SHARED_ATTN,
 from repro.layers.attention import (AttnOpts, attn_decode, attn_decode_paged,
                                     attn_forward, fill_kv_cache,
                                     init_attention, init_kv_cache,
-                                    init_paged_kv_pool)
+                                    init_paged_kv_pool, sweep_chunk_cols)
 from repro.layers.mla import (MLAOpts, fill_mla_cache, init_mla,
                               init_mla_cache, mla_decode, mla_forward)
 from repro.layers.mlp import init_mlp, mlp_forward
@@ -263,6 +263,20 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int, dtype):
         else:
             out.append(tuple(stacked(s, st.repeats) for s in st.sites))
     return tuple(out)
+
+
+def paged_sweep_layers(cfg: ModelConfig, pool, batch: int, n_cols: int):
+    """``(window, chunk columns, layers)`` of each attention site of a
+    paged ``pool`` (from ``init_paged_cache``): how its decode sweep
+    chunks a (batch, n_cols) block table (``attn_decode_paged``)."""
+    out = []
+    for st, c in zip(plan_stages(cfg), pool):
+        for site, leaf in zip(st.sites, (c,) if st.kind == "run" else c):
+            n, _, ps, kv, hd = leaf["k"].shape
+            out.append((site.window,
+                        sweep_chunk_cols(batch, n_cols, ps, kv, hd,
+                                         leaf["k"].dtype.itemsize), n))
+    return out
 
 
 # ---------------------------------------------------------------------------

@@ -22,7 +22,9 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.analysis.lifecycle import sanitizer
 from repro.configs.base import ModelConfig
 from repro.core.spans import span
+from repro.layers.attention import swept_cols
 from repro.models.api import Model
+from repro.models.stages import paged_sweep_layers
 from repro.runtime.paged import PagePoolManager, default_pool_pages
 from repro.runtime.sharding import (batch_specs, cache_specs, dp_axes, named,
                                     param_specs)
@@ -336,6 +338,8 @@ class BatchingEngine:
                                         scrub_on_free=scrub_on_free)
             self.caches = self._alloc(
                 lambda: model.make_paged_caches(cache_pages, page_size))
+            self._sweep_layers = paged_sweep_layers(
+                model.cfg, self.caches, n_slots, max_blocks)
             self._pos = np.full((n_slots,), -1, np.int32)
             step = make_paged_serve_step(model)
             self._decode = jax.jit(step)
@@ -863,6 +867,18 @@ class BatchingEngine:
             self._bt_version = self.pool.version
         return self._bt_cache
 
+    def _sweep_counts(self) -> Dict[str, int]:
+        """Block-table columns the paged decode sweep covers at the
+        current positions, and the table's columns, each summed over the
+        paged attention layers: the same bound the device computes."""
+        nb = self.pool.block_tables.shape[1]
+        swept = total = 0
+        for window, chunk, layers in self._sweep_layers:
+            swept += layers * swept_cols(self._pos, self.page_size, nb,
+                                         window, chunk)
+            total += layers * nb
+        return {"table_cols_swept": swept, "table_cols": total}
+
     def _step_single(self, slot: int, token: int, pos: int):
         """Replay ONE context token through the decode program (short or
         legacy-mode prefill). The logits are deliberately dropped on
@@ -970,7 +986,9 @@ class BatchingEngine:
                   if r is not None and i not in self._prefilling]
         if not active:
             return 0
-        with span("rc3e.engine.decode_dispatch"):
+        with span("rc3e.engine.decode_dispatch") as sp:
+            if sp and self.paged:
+                sp.set(**self._sweep_counts())
             tokens = np.zeros((self.n_slots, 1), np.int32)
             for i in active:
                 tokens[i, 0] = self._slots[i]._next_input

@@ -8,13 +8,15 @@ times are out of scope. The topology is described inside a fixture, so
 only the test process that runs this file loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.configs.base import GeometryConfig
+from repro.configs.base import ATTN_LOCAL, GeometryConfig
 from repro.kernels import ops
 from repro.models import get_model
 from repro.runtime.paged import default_pool_pages
@@ -113,3 +115,47 @@ def test_full_width_paged_decode_step(one_chip):
             _sds(one_chip, (B, L // PAGE), jnp.int32))
     compiled = jax.jit(make_paged_serve_step(model)).lower(*args).compile()
     assert compiled.memory_analysis().argument_size_in_bytes < 2 * 2 ** 30
+
+
+def _materialised(hlo: str):
+    """(dtype, dims) of every array an instruction outside a fusion body
+    writes: what the compiled program holds in memory between ops."""
+    fused = set(re.findall(r"calls=%([\w.\-]+)", hlo))
+    out, skip = [], False
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            skip = head.group(1) in fused
+        elif not skip:
+            m = re.match(r"\s+(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]", line)
+            if m:
+                out.append((m.group(1),
+                            tuple(int(d) for d in m.group(2).split(",") if d)))
+    return out
+
+
+def test_phi3_paged_decode_step_sweeps_pages_in_bf16(one_chip):
+    """phi3-mini's paged decode step as the benchmark deploys it (4 slots,
+    4096 positions, 289 pages of 16, every layer windowed at 2047): the
+    chunked page sweep compiles as a loop inside the layer scan, and the
+    gathered K and V enter the dots in bf16, with no f32 copy of them."""
+    cfg = get_config("phi3-mini-3.8b").replace(
+        pattern=(ATTN_LOCAL,), window=2047, dtype="bfloat16",
+        param_dtype="bfloat16")
+    model = get_model(cfg)
+    slots, max_len, pages = 4, 4096, 289
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
+    pool = jax.eval_shape(lambda: model.make_paged_caches(pages, PAGE))
+    args = (_placed(one_chip, params), _placed(one_chip, pool),
+            _sds(one_chip, (slots, 1), jnp.int32),
+            _sds(one_chip, (slots,), jnp.int32),
+            _sds(one_chip, (slots, max_len // PAGE), jnp.int32))
+    hlo = jax.jit(make_paged_serve_step(model)).lower(*args).compile() \
+        .as_text()
+    assert hlo.count(" while(") == 2          # the layer scan, the sweep
+    kv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+    page_of_batch = slots * PAGE * kv * hd
+    f32_kv = [dims for dtype, dims in _materialised(hlo)
+              if dtype == "f32" and dims[-2:] == (kv, hd)
+              and np.prod(dims) >= page_of_batch]
+    assert f32_kv == []

@@ -3,11 +3,13 @@ engine equivalence, COW prefix sharing, page-quota queue-on-exhaustion,
 page-copy hand-off, monitor occupancy — plus the engine lifecycle
 satellites (queue pruning, not-drained signal, in-flight cancel)."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_config, reduced
 from repro.core import ClusterSpec, Hypervisor
+from repro.layers import attention as attn
 from repro.models import get_model
 from repro.runtime import BatchingEngine, GatewayFleet, ServingGateway
 from repro.runtime.paged import PagePoolManager
@@ -100,6 +102,156 @@ def test_pool_pages_needed_counts_sharing():
     assert pool.pages_needed("t", toks) == 0         # fully shareable now
     assert pool.pages_needed("t", toks, share=False) == 3
     assert pool.pages_needed("other", toks) == 3
+
+
+# ---------------------------------------------------------------------------
+# Paged decode sweep == whole-table attention
+# ---------------------------------------------------------------------------
+
+PS, NB = 4, 16            # page size and block-table columns (64 positions)
+
+
+def _whole_table(p, x, positions, cache, block_tables, opts):
+    """The paged decode's attention over every column of the block table,
+    dequantised up front, in one softmax: the sweep's oracle."""
+    B = x.shape[0]
+    q, _, _ = attn._qkv(p, x, positions, opts)
+    k, v = cache["k"][block_tables], cache["v"][block_tables]
+    if "k_scale" in cache:
+        k = attn._deq(k, cache["k_scale"][block_tables], x.dtype)
+        v = attn._deq(v, cache["v_scale"][block_tables], x.dtype)
+    k = k.reshape((B, -1) + k.shape[3:])
+    v = v.reshape((B, -1) + v.shape[3:])
+    kpos = cache["pos"][block_tables].reshape(B, -1)
+    mask = attn._causal_mask(positions, kpos, opts.window, opts.causal,
+                             k_valid=kpos >= 0)
+    y = attn._attend(q, k, v, mask, opts)
+    return jnp.einsum("bshgk,hgkd->bsd", y, p["wo"].astype(x.dtype))
+
+
+def _paged_batch(lens, opts, quant, seed=0):
+    """Random bf16 params and pool; row b holds ``lens[b]`` live positions
+    (0 = inactive row: pos -1, an all-null block table) on pages of its
+    own in every column, unwritten positions at pos -1."""
+    B, kv, hd = len(lens), opts.n_kv_heads, opts.head_dim
+    d = opts.n_heads * hd
+    kp, kx, kk, kv_ = jax.random.split(jax.random.PRNGKey(seed), 4)
+    p = attn.init_attention(kp, d, opts, jnp.bfloat16)
+    x = jax.random.normal(kx, (B, 1, d), jnp.bfloat16)
+    n_pages = 1 + B * NB
+    pool = attn.init_paged_kv_pool(n_pages, PS, opts, jnp.bfloat16, quant)
+    k = jax.random.normal(kk, pool["k"].shape, jnp.float32)
+    v = jax.random.normal(kv_, pool["v"].shape, jnp.float32)
+    if quant:
+        pool["k"], pool["k_scale"] = attn._quant_rows(k)
+        pool["v"], pool["v_scale"] = attn._quant_rows(v)
+    else:
+        pool["k"], pool["v"] = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
+    tables = np.zeros((B, NB), np.int32)
+    kpos = np.full((n_pages, PS), -1, np.int32)
+    for b, n in enumerate(lens):
+        if n:
+            tables[b] = 1 + b * NB + np.arange(NB)
+            for t in range(n - 1):               # the step writes n - 1
+                kpos[tables[b, t // PS], t % PS] = t
+    pool["pos"] = jnp.asarray(kpos)
+    pos = np.array([n - 1 if n else -1 for n in lens], np.int32)
+    return p, x, pool, tables, pos
+
+
+# live lengths per row (0 = inactive) cover 1, ps - 1, C, C + 1 and
+# nb * ps - 1 positions, C = 4 pages = 16 positions a chunk
+@pytest.mark.parametrize("lens,window,kv,g,quant,cap,chunk", [
+    ((1, 3, 16, 17), 0, 4, 1, False, 0.0, 4),
+    ((63, 0, 17, 1), 0, 2, 3, False, 0.0, 4),
+    ((63, 50, 0, 36), 20, 4, 1, False, 0.0, 4),    # windows from 16 on
+    ((35, 63, 50, 0), 20, 2, 3, True, 0.0, 4),     # windows from 15 on
+    ((40, 16, 0, 2), 0, 4, 1, False, 30.0, 4),
+    ((63, 50, 45, 0), 20, 2, 3, True, 0.0, 3),     # table padded to 18
+    ((17, 63, 1, 0), 0, 2, 3, False, 0.0, NB),     # one pass
+], ids=["global-mha", "global-gqa-inactive", "window-past-first-chunk",
+        "window-int8", "softcap", "int8-ragged-chunk", "one-pass"])
+def test_paged_sweep_matches_whole_table(monkeypatch, lens, window, kv, g,
+                                         quant, cap, chunk):
+    """``attn_decode_paged`` sweeping ``chunk`` columns at a time agrees
+    with one softmax over the whole table, within bf16 rounding; the
+    columns it reads are exactly those ``swept_cols`` counts for the
+    engine: a NaN planted in V poisons the output iff its column is
+    swept."""
+    opts = attn.AttnOpts(n_heads=kv * g, n_kv_heads=kv, head_dim=8,
+                         window=window, softcap=cap)
+    p, x, pool, tables, pos = _paged_batch(lens, opts, quant)
+    B, itemsize = len(lens), 1 if quant else 2
+    per_col = 2 * B * PS * kv * opts.head_dim * itemsize
+    monkeypatch.setattr(attn, "SWEEP_CHUNK_BYTES", chunk * per_col)
+    assert attn.sweep_chunk_cols(B, NB, PS, kv, opts.head_dim,
+                                 itemsize) == chunk
+    step = jax.jit(lambda pool, bt: attn.attn_decode_paged(
+        p, x, jnp.asarray(pos)[:, None], pool, bt, opts))
+    bt = jnp.asarray(tables)
+    out, written = step(pool, bt)
+    want = _whole_table(p, x, jnp.asarray(pos)[:, None], written, bt, opts)
+    live = pos >= 0
+    np.testing.assert_allclose(np.asarray(out, np.float32)[live],
+                               np.asarray(want, np.float32)[live],
+                               atol=3e-2, rtol=3e-2)
+
+    lo, hi = attn.sweep_chunks(jnp.asarray(pos), PS, NB, window, chunk)
+    assert (int(lo), int(hi)) == tuple(
+        int(b) for b in attn.sweep_chunks(pos, PS, NB, window, chunk, np))
+    leaf = "v_scale" if quant else "v"
+    swept = []
+    for col in range(NB):
+        bad = pool[leaf].at[tables[live, col]].set(jnp.nan)
+        out, _ = step(dict(pool, **{leaf: bad}), bt)
+        if np.isnan(np.asarray(out, np.float32)[live]).any():
+            swept.append(col)
+    live_pos = pos[live]
+    first = min(np.maximum(live_pos - window + 1, 0)) if window else 0
+    c0 = first // PS // chunk * chunk
+    c1 = min(NB, (max(live_pos) // PS // chunk + 1) * chunk)
+    assert swept == list(range(c0, c1))
+    assert len(swept) == attn.swept_cols(pos, PS, NB, window, chunk)
+    assert c0 == int(lo) * chunk
+
+
+@pytest.mark.parametrize("chunked", [False, True])
+def test_sweep_counts_on_decode_span(served_model, monkeypatch, tmp_path,
+                                     chunked):
+    """The engine stamps the decode dispatch span with the block-table
+    columns the sweep covers at the step's positions, summed over the
+    layers: the whole table when it is one chunk, else the chunks up to
+    the furthest position. Chunked or not, the tokens are the same."""
+    from repro.core import spans
+    cfg, model, params = served_model
+    prompt = _prompt(cfg, 20)
+
+    def serve():
+        eng = BatchingEngine(model, params, n_slots=2, max_len=64,
+                             paged=True, page_size=16)
+        req = eng.submit(prompt, max_new_tokens=6)
+        assert eng.run_until_idle() is True
+        return req.out_tokens
+
+    unchunked = serve()
+    if chunked:      # one 16-position page a chunk
+        per_col = 2 * 2 * 16 * cfg.n_kv_heads * cfg.resolved_head_dim * 4
+        monkeypatch.setattr(attn, "SWEEP_CHUNK_BYTES", per_col)
+    spans.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        out = serve()
+    finally:
+        jax.profiler.stop_trace()
+    got = [s.attrs for s in spans.recorded()
+           if s.name == "rc3e.engine.decode_dispatch"]
+    spans.clear()
+    assert out == unchunked
+    # positions 19..24 lie in the table's second of four columns
+    swept = 2 if chunked else 4
+    assert len(got) == 6 and all(
+        a == {"table_cols_swept": swept * cfg.n_layers,
+              "table_cols": 4 * cfg.n_layers} for a in got)
 
 
 # ---------------------------------------------------------------------------
