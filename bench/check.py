@@ -13,12 +13,12 @@ gap opens only where rounding flips a near tie.
 """
 from __future__ import annotations
 
+from types import ModuleType
 from typing import Dict, List
 
 import numpy as np
 
 import reference
-from dims import Dims
 from loop import Record, Sent
 
 
@@ -59,18 +59,19 @@ def _numbers(mean: float, tokens: int, tenants_seen: int, limits: dict,
     }
 
 
-def judge(m: Dims, seed: int, seqs, limits: dict, n_tenants: int,
-          tenants_seen: int, control: bool = False):
+def judge(arch: ModuleType, m, seed: int, seqs, limits: dict,
+          n_tenants: int, tenants_seen: int, control: bool = False):
     """(the numbers compared, each with its limit; readings beside them
     that are not compared: the widest gap and, with ``control``, the
     control's own; with ``control``, the control's numbers under the same
-    limits, else None)."""
+    limits, else None). ``arch`` and ``m``: the architecture module and
+    its sizes."""
     tokens = sum(len(out) for _, out in seqs)
     bad = any(t < 0 or t >= m.vocab for _, out in seqs for t in out)
     mean = widest = ctl_mean = float("inf")
     readings = {}
     if seqs and not bad:
-        gaps, ctl_gaps = reference.served_gaps(m, seed, seqs, control)
+        gaps, ctl_gaps = reference.served_gaps(arch, m, seed, seqs, control)
         flat = np.concatenate(gaps)
         mean, widest = float(flat.mean()), float(flat.max())
         readings["flip_share"] = float(np.mean(flat > 0))

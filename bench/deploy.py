@@ -3,7 +3,8 @@ hypervisor with one node of ``devices`` devices, the paged
 ``GatewayFleet`` serving the model, and one serving session per tenant.
 
 The same steps as ``repro.launch.serve.build_fleet``/``open_tenants``,
-with the pool size and each tenant's service model taken from the file.
+with the pool size and each tenant's service model taken from the file,
+and the model from the configuration's architecture module (``program``).
 """
 from __future__ import annotations
 
@@ -11,33 +12,15 @@ from typing import List, Tuple
 
 import jax
 
-from dims import Dims
-
-
-def model_config(m: Dims):
-    """The program's ``ModelConfig`` for these sizes, served in bfloat16:
-    every layer windowed where the source states a sliding window."""
-    from repro.configs.base import ATTN_GLOBAL, ATTN_LOCAL, ModelConfig
-    local = dict(pattern=(ATTN_LOCAL,), window=m.window) if m.window \
-        else dict(pattern=(ATTN_GLOBAL,))
-    return ModelConfig(
-        name=m.name, family="dense", n_layers=m.n_layers,
-        d_model=m.d_model, n_heads=m.n_heads, n_kv_heads=m.n_kv_heads,
-        head_dim=m.head_dim, d_ff=m.d_ff, vocab_size=m.vocab,
-        rope_theta=m.rope_theta, **local,
-        tie_embeddings=m.tied, max_seq_len=m.max_position,
-        norm_eps=m.norm_eps, act="silu", dtype="bfloat16",
-        param_dtype="bfloat16")
-
-
-def build(m: Dims, dep: dict, params) -> Tuple[object, object, List[str]]:
-    """(hypervisor, fleet, tenant names). Fails unless every device of the
-    deployment got its own engine on its own chip."""
+def build(cfg, dep: dict, params) -> Tuple[object, object, List[str]]:
+    """(hypervisor, fleet, tenant names) serving the program's
+    ``ModelConfig`` ``cfg``. Fails unless every device of the deployment
+    got its own engine on its own chip."""
     from repro.core import ClusterSpec, Hypervisor
     from repro.models import get_model
     from repro.runtime import GatewayFleet
     hv = Hypervisor(ClusterSpec(n_nodes=1, devices_per_node=dep["devices"]))
-    fleet = GatewayFleet(hv, get_model(model_config(m)), params,
+    fleet = GatewayFleet(hv, get_model(cfg), params,
                          n_slots=dep["n_slots"], max_len=dep["max_len"],
                          paged=True, page_size=dep["page_size"],
                          cache_pages=dep.get("cache_pages"))

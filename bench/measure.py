@@ -1,24 +1,26 @@
 """What a metric reader gets: the run's host record, its reduced trace (in
-a traced run), the configuration's sizes and the chip's peaks, with the
-arithmetic the readers share. The operations and bytes a step needs are
-computed here from the configuration's shapes, never taken from the
-program.
+a traced run), the configuration's architecture module and sizes and the
+chip's peaks, with the arithmetic the readers share. The operations and
+bytes a step needs are computed by the architecture module from the
+configuration's shapes (``decode_work``, ``prefill_flops``), never taken
+from the program; here they are summed over the traced steps.
 """
 from __future__ import annotations
 
 import dataclasses
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import numpy as np
 
-from dims import Dims
 from loop import Record, Step
 
 
 @dataclasses.dataclass
 class Run:
     rec: Record
-    dims: Dims
+    arch: ModuleType                    # bench/arch/<arch>.py
+    dims: object                        # the sizes ``arch.sizes`` read
     deployment: dict
     chips: int
     peak: dict                          # the device's row of peaks.json
@@ -52,41 +54,33 @@ class Run:
     # ---- work the traced steps needed ----
     def decode_work(self, steps: List[Step]):
         """(executions, bytes, flops) of the decode steps in ``steps``: one
-        execution per device that decoded, reading the weights once, the
-        live KV of each slot (the window's, where the model has one) and
-        writing one position per slot."""
-        m = self.dims
-        execs, kv_pos, flops = 0, 0, 0.0
-        per_token = 2.0 * (m.n_layers * m.layer_matmul_params
-                           + m.head_params)
-        attn = 4.0 * m.n_layers * m.n_heads * m.head_dim
+        execution per device that decoded, over the rows it decoded, each
+        counted by the architecture's ``decode_work``."""
+        execs, nbytes, flops = 0, 0, 0.0
         for st in steps:
-            devs = set()
+            rows: Dict[str, List[int]] = {}
             for i, j in st.tokens:
                 s = self.rec.sent[i]
-                ctx = m.attended(len(s.arrival.prompt) + j)
-                devs.add(self.device_of[s.tenant])
-                kv_pos += ctx + 1
-                flops += per_token + attn * ctx
-            execs += len(devs)
-        nbytes = execs * m.weight_bytes_per_step \
-            + kv_pos * m.kv_bytes_per_position
+                rows.setdefault(self.device_of[s.tenant], []).append(
+                    len(s.arrival.prompt) + j)
+            for ctxs in rows.values():
+                b, f = self.arch.decode_work(self.dims, ctxs)
+                execs += 1
+                nbytes += b
+                flops += f
         return execs, nbytes, flops
 
     def prefill_work(self, steps: List[Step]):
         """(real context tokens, flops) of the prefills in ``steps``: a
         request's first token comes from the step that prefilled its
         prompt but the last token, which that step decodes."""
-        m = self.dims
         toks, flops = 0, 0.0
         for st in steps:
             for i, j in st.tokens:
                 if j == 0:
                     S = len(self.rec.sent[i].arrival.prompt) - 1
                     toks += S
-                    flops += 2.0 * m.n_layers * m.layer_matmul_params * S \
-                        + 4.0 * m.n_layers * m.n_heads * m.head_dim \
-                        * m.attended_prefill(S)
+                    flops += self.arch.prefill_flops(self.dims, S)
         return toks, flops
 
 

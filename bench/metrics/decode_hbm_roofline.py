@@ -1,5 +1,6 @@
-"""The bytes the traced decode steps need (the weights once per step,
-each slot's live keys and values, one position written per slot) over
+"""The bytes the traced decode steps need (the architecture module's
+``decode_work`` of each execution: for a dense model the weights once,
+each slot's live keys and values and one position written per slot) over
 what the chip's HBM bandwidth moves in their device time, in percent.
 The bound is bandwidth: a decode step of a few slots does far fewer
 operations per byte than the chip's balance point."""

@@ -1,6 +1,8 @@
 """The operations the traced window's prefills and decode steps need
-(2 per weight per token, plus causal attention), over what the cell's
-chips could do at their bf16 peak in the traced window, in percent."""
+(the architecture module's ``decode_work`` and ``prefill_flops``: for a
+dense model 2 per weight per token, plus causal attention), over what the
+cell's chips could do at their bf16 peak in the traced window, in
+percent."""
 
 
 def read(run):

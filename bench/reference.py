@@ -1,23 +1,22 @@
-"""Plain float32 reference forward pass of a dense decoder-only LM with
-grouped-query attention (global, or a sliding window of ``Dims.window``
-positions: a query attends the keys less than the window behind it),
-RoPE and a SiLU-gated MLP, and the comparison that decides ``correct``.
+"""The float32 reference of the served model, and the helpers every
+architecture's reference layer is written with.
 
-Written straight in ``jax.numpy``: one layer after another, the whole
-masked score matrix, no cache, no kernels, float32 at
-``Precision.HIGHEST``. It imports nothing of the program: it draws its
-weights from the seed (``weights.layer``/``weights.top``) and reads only
-the prompts and the tokens the program served. RMSNorm weights are
-stored as ``w - 1`` and applied as ``1 + w``; RoPE rotates the two halves
-of each head.
+An architecture module (``bench/arch/<arch>.py``, found by ``spec.arch``)
+writes its layer, logits and embedding straight in ``jax.numpy`` with
+these helpers: float32 at ``Precision.HIGHEST``, no cache, no kernels.
+``served_gaps`` walks its layers one after another. The reference imports
+nothing of the program: it draws its weights from the seed
+(``weights.layer``/``weights.top``) and reads only the prompts and the
+tokens the program served.
 
 ``quant="fp8"`` runs the same pass with every matmul's weights and inputs
 rounded to float8 e4m3 (one scale per tensor or per row): the control,
-one precision step below the served bfloat16.
+one precision step below the served bfloat16. The helpers are shared, so
+the control means the same for every architecture.
 """
 from __future__ import annotations
 
-import functools
+from types import ModuleType
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -25,7 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 
 import weights
-from dims import Dims
 
 HI = jax.lax.Precision.HIGHEST
 PAD = 256          # sequences are padded to a multiple: few compiled shapes
@@ -59,44 +57,17 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 3))
-def _layer(m: Dims, p, x, quant):
-    S, d = x.shape
-    hd, kv, g = m.head_dim, m.n_kv_heads, m.n_heads // m.n_kv_heads
-    h = _rms_norm(x, p["norm1"], m.norm_eps)
-    q = _mm(h, p["attn/wq"].reshape(d, -1), quant).reshape(S, kv * g, hd)
-    k = _mm(h, p["attn/wk"].reshape(d, -1), quant).reshape(S, kv, hd)
-    v = _mm(h, p["attn/wv"].reshape(d, -1), quant).reshape(S, kv, hd)
-    q = _rope(q, m.rope_theta).reshape(S, kv, g, hd)
-    k = _rope(k, m.rope_theta)
-    scores = jnp.einsum("qhgc,khc->hgqk", q * hd ** -0.5, k, precision=HI)
-    diff = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
-    allowed = (diff >= 0) & ((diff < m.window) if m.window else True)
-    probs = jax.nn.softmax(jnp.where(allowed, scores, -jnp.inf), axis=-1)
-    o = jnp.einsum("hgqk,khc->qhgc", probs, v, precision=HI).reshape(S, -1)
-    x = x + _mm(o, p["attn/wo"].reshape(-1, d), quant)
-    h = _rms_norm(x, p["norm2"], m.norm_eps)
-    y = jax.nn.silu(_mm(h, p["mlp/wg"], quant)) * _mm(h, p["mlp/wu"], quant)
-    return x + _mm(y, p["mlp/wd"], quant)
-
-
-@functools.partial(jax.jit, static_argnums=(0, 3))
-def _logits(m: Dims, top, h, quant):
-    h = _rms_norm(h, top["final_norm"], m.norm_eps)
-    head = top["embed/tok"].T if m.tied else top["head"]
-    return _mm(h, head, quant)
-
-
-def served_gaps(m: Dims, seed: int,
+def served_gaps(arch: ModuleType, m, seed: int,
                 seqs: Sequence[Tuple[np.ndarray, Sequence[int]]],
                 control: bool = False
                 ) -> Tuple[List[np.ndarray], Optional[List[np.ndarray]]]:
     """For each (prompt, served tokens): at every served position, how far
-    the served token's reference logit lies below the reference's best.
-    With ``control``, also the same gap for the token the fp8 pass puts
-    first. Runs layer by layer over all sequences, so only one layer's
-    weights are on the device at a time."""
-    lay = weights.layout(m)
+    the served token's reference logit lies below the reference's best,
+    for the architecture ``arch`` at sizes ``m``. With ``control``, also
+    the same gap for the token the fp8 pass puts first. Runs layer by
+    layer over all sequences, so only one layer's weights are on the
+    device at a time."""
+    lay = arch.layout(m)
     top = weights.top(lay, seed)
     streams = [None, "fp8"] if control else [None]
     xs = {q: [] for q in streams}
@@ -107,14 +78,14 @@ def served_gaps(m: Dims, seed: int,
         S = len(full)
         toks = np.zeros((-(-S // PAD) * PAD,), np.int32)
         toks[:S] = full
-        emb = top["embed/tok"][jnp.asarray(toks)]
+        emb = arch.embed(m, top, jnp.asarray(toks))
         for q in streams:
             xs[q].append(emb)
         spans.append((len(prompt) - 1, S, np.asarray(out, np.int64)))
     for r in range(m.n_layers):
-        p = weights.layer(lay, seed, r)
+        p = weights.layer(lay, seed, *arch.layer_at(m, r))
         for q in streams:
-            xs[q] = [_layer(m, p, x, q) for x in xs[q]]
+            xs[q] = [arch.layer(m, r, p, x, q) for x in xs[q]]
         del p
     ref_gaps, ctl_gaps = [], []
     for i, (a, b, out) in enumerate(spans):
@@ -122,11 +93,12 @@ def served_gaps(m: Dims, seed: int,
         # the served rows, padded to a multiple of PAD: few compiled shapes
         rows = jnp.asarray(np.minimum(a + np.arange(-(-n // PAD) * PAD),
                                       xs[None][i].shape[0] - 1))
-        ref = np.asarray(_logits(m, top, xs[None][i][rows], None))[:n]
+        ref = np.asarray(arch.logits(m, top, xs[None][i][rows], None))[:n]
         best = ref.max(axis=-1)
         ref_gaps.append(best - ref[np.arange(n), out])
         if control:
-            ctl = np.asarray(_logits(m, top, xs["fp8"][i][rows], "fp8"))[:n]
+            ctl = np.asarray(arch.logits(m, top, xs["fp8"][i][rows],
+                                         "fp8"))[:n]
             first = ctl.argmax(axis=-1)
             ctl_gaps.append(best - ref[np.arange(n), first])
     return ref_gaps, (ctl_gaps if control else None)

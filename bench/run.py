@@ -109,21 +109,21 @@ class Stand:
         import trace_reduce
         import warmup
         import weights
-        from dims import Dims
         from repro.models import get_model
 
         self.cell = spec.cell(bench, cell_name)
         self.conf = spec.config(bench, self.cell, root)
         self.mix = spec.traffic(self.cell, root)
-        self.dims = Dims.of(self.cell["config"], self.conf["model"])
+        self.arch = spec.arch(self.conf, root)
+        self.dims = self.arch.sizes(self.cell["config"], self.conf["model"])
         self.dep = self.conf["deployment"]
         self.peak = peak or peak_of(devices[0].device_kind)
-        lay = weights.layout(self.dims)
-        program = get_model(deploy.model_config(self.dims))
-        weights.check_layout(lay, jax.eval_shape(program.init,
+        lay = self.arch.layout(self.dims)
+        cfg = self.arch.program(self.dims)
+        weights.check_layout(lay, jax.eval_shape(get_model(cfg).init,
                                                  jax.random.PRNGKey(0)))
         params = weights.make_params(lay, seed, devices[0])
-        self.hv, self.fleet, self.tenants = deploy.build(self.dims, self.dep,
+        self.hv, self.fleet, self.tenants = deploy.build(cfg, self.dep,
                                                          params)
         del params
         self.warm = warmup.run(self.fleet, self.tenants, self.mix, self.dep,
@@ -185,7 +185,7 @@ def execute(bench: dict, cell_name: str, seed: int, seconds: float,
     memory_peak = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
                    for d in st.chips]
 
-    run = Run(rec=rec, dims=st.dims, deployment=st.dep,
+    run = Run(rec=rec, arch=st.arch, dims=st.dims, deployment=st.dep,
               chips=st.cell["chips"], peak=st.peak, setup_s=setup_s,
               memory_peak=memory_peak,
               device_of={t: st.fleet.device_of(t) for t in st.tenants},
@@ -204,7 +204,7 @@ def execute(bench: dict, cell_name: str, seed: int, seconds: float,
     del run, chosen
     st.close()
     numbers, readings, ctl_numbers = check.judge(
-        st.dims, seed, seqs, st.conf["check"], len(st.tenants),
+        st.arch, st.dims, seed, seqs, st.conf["check"], len(st.tenants),
         tenants_seen, control=control)
 
     result = {

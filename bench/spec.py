@@ -1,18 +1,40 @@
-"""Finds what ``BENCHMARK.json`` names: a cell, its configuration file, its
-traffic mix and the reader of each metric.
+"""Finds what ``BENCHMARK.json`` names: a cell, its configuration file and
+the architecture that file names, its traffic mix and the reader of each
+metric.
 
-Every piece is found by name, so a new configuration, mix, cell or metric
-is a new file plus an entry in ``BENCHMARK.json``:
+Every piece is found by name, so a new configuration, architecture, mix,
+cell or metric is a new file plus an entry in ``BENCHMARK.json``:
 
   configuration  the ``file`` of its entry under ``configs``
+  architecture   ``bench/arch/<arch>.py``, named by the configuration
+                 file's ``"arch"`` key
   traffic mix    ``bench/traffic/<traffic>.json``
   metric         ``bench/metrics/<name>.py``, a module with ``read(run)``
+
+An architecture module owns everything that depends on the model's
+shape, and nothing else:
+
+  sizes(name, model)         the file's ``model`` block read once: a
+                             frozen, hashable value with at least
+                             ``vocab`` and ``n_layers``
+  program(m)                 the program's ``ModelConfig``
+  layout(m)                  the weight layout (``weights.Layout``)
+  layer_at(m, r)             (prefix, index): layer ``r``'s weights are
+                             entry ``index`` of the stacked leaves under
+                             ``prefix`` (a stage, or a position in one)
+  layer(m, r, p, x, quant)   the float32 reference layer ``r``
+  logits(m, top, h, quant)   the float32 reference head
+  embed(m, top, toks)        the float32 reference embedding
+  decode_work(m, ctxs)       (bytes, flops) of one decode execution over
+                             rows at contexts ``ctxs``
+  prefill_flops(m, S)        operations of a prefill of ``S`` tokens
 """
 from __future__ import annotations
 
 import importlib.util
 import json
 import pathlib
+import sys
 from types import ModuleType
 from typing import Dict, List
 
@@ -65,20 +87,41 @@ def metrics(bench: dict, cell_name: str, kind: str) -> List[dict]:
             if cell_name in m.get("workloads", [cell_name])]
 
 
-_READERS: Dict[pathlib.Path, ModuleType] = {}
+_MODULES: Dict[pathlib.Path, ModuleType] = {}
+
+
+def _module(path: pathlib.Path) -> ModuleType:
+    """The module at ``path``, loaded once (and registered, as a
+    dataclass in it needs)."""
+    if path not in _MODULES:
+        spec = importlib.util.spec_from_file_location(
+            f"bench_{path.parent.name}_{len(_MODULES)}", path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[spec.name] = mod
+        spec.loader.exec_module(mod)
+        _MODULES[path] = mod
+    return _MODULES[path]
 
 
 def reader(name: str, where: pathlib.Path = BENCH_DIR / "metrics"
            ) -> ModuleType:
     """The module ``bench/metrics/<name>.py``; it defines ``read(run)``."""
     path = where / f"{name}.py"
-    if path not in _READERS:
-        if not path.is_file():
-            raise SpecError(f"metric {name!r} has no reader "
-                            f"bench/metrics/{name}.py")
-        spec = importlib.util.spec_from_file_location(
-            f"bench_metric_{len(_READERS)}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        _READERS[path] = mod
-    return _READERS[path]
+    if not path.is_file():
+        raise SpecError(f"metric {name!r} has no reader "
+                        f"bench/metrics/{name}.py")
+    return _module(path)
+
+
+def arch(conf: dict, root: pathlib.Path = ROOT) -> ModuleType:
+    """The module ``bench/arch/<arch>.py`` of the architecture a
+    configuration file names: the checkout's at ``root``, else the
+    harness's own (a test's checkout may hold only configuration files)."""
+    if "arch" not in conf:
+        raise SpecError("the configuration file names no \"arch\"")
+    for where in (root / "bench" / "arch", BENCH_DIR / "arch"):
+        path = where / f"{conf['arch']}.py"
+        if path.is_file():
+            return _module(path)
+    raise SpecError(f"architecture {conf['arch']!r} has no module "
+                    f"bench/arch/{conf['arch']}.py")

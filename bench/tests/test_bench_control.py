@@ -43,20 +43,20 @@ def test_reference_window_masks_only_past_it():
     import jax.numpy as jnp
     import numpy as np
 
-    import reference
     import weights
-    from dims import Dims
+    dense = spec.arch({"arch": "dense"})
+    Dims = dense.Dims
     base = Dims("w", n_layers=1, d_model=64, n_heads=4, n_kv_heads=2,
                 head_dim=16, d_ff=128, vocab=512, tied=True, norm_eps=1e-5,
                 rope_theta=1e4, max_position=256)
-    p = weights.layer(weights.layout(base), 5, 0)
+    p = weights.layer(dense.layout(base), 5, *dense.layer_at(base, 0))
     x = jnp.asarray(np.random.default_rng(0).normal(size=(40, 64)),
                     jnp.float32)
-    full = np.asarray(reference._layer(base, p, x, None))
-    wide = np.asarray(reference._layer(
-        Dims(**{**base.__dict__, "window": 40}), p, x, None))
-    narrow = np.asarray(reference._layer(
-        Dims(**{**base.__dict__, "window": 24}), p, x, None))
+    full = np.asarray(dense.layer(base, 0, p, x, None))
+    wide = np.asarray(dense.layer(
+        Dims(**{**base.__dict__, "window": 40}), 0, p, x, None))
+    narrow = np.asarray(dense.layer(
+        Dims(**{**base.__dict__, "window": 24}), 0, p, x, None))
     np.testing.assert_array_equal(full, wide)
     np.testing.assert_array_equal(full[:24], narrow[:24])
     assert np.abs(full[24:] - narrow[24:]).max(axis=-1).min() > 1e-4

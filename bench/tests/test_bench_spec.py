@@ -1,6 +1,7 @@
 """Everything ``BENCHMARK.json`` names is found by name; a new
-configuration, mix, cell or metric is new files and entries only; the
-harness refuses to run without a chip and on an unknown device."""
+configuration, architecture, mix, cell or metric is new files and entries
+only; the harness refuses to run without a chip and on an unknown
+device."""
 import json
 import os
 import pathlib
@@ -13,9 +14,9 @@ import pytest
 
 import run
 import spec
-from dims import Dims
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
+FIXTURES = pathlib.Path(__file__).resolve().parent / "fixtures"
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -24,7 +25,9 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 def test_every_name_resolves():
     for cell in BENCH["workloads"]:
         conf = spec.config(BENCH, cell)
-        Dims.of(cell["config"], conf["model"])
+        m = spec.arch(conf).sizes(cell["config"], conf["model"])
+        assert m.vocab == conf["model"]["vocab_size"]
+        hash(m)
         mix = spec.traffic(cell)
         assert mix["arrivals"] == "poisson"
         for kind in ("end_to_end", "per_layer"):
@@ -109,6 +112,64 @@ def test_unknown_names_are_errors():
         spec.traffic({"traffic": "no-such-mix"})
     with pytest.raises(spec.SpecError):
         spec.reader("no_such_metric")
+    with pytest.raises(spec.SpecError):
+        spec.arch({"arch": "no_such_arch"})
+    with pytest.raises(spec.SpecError):       # no default architecture
+        spec.arch({"model": {}})
+
+
+def _files(root: pathlib.Path) -> dict:
+    return {p: p.read_bytes() for p in root.rglob("*")
+            if "__pycache__" not in p.parts and p.is_file()}
+
+
+def test_adding_an_architecture_edits_no_file(tmp_path, monkeypatch):
+    """A new architecture (layers alternating a window and global
+    attention: a "pattern" stage the dense module cannot describe), a
+    configuration that names it and a cell are new files and entries; the
+    cell runs correct on the CPU, and every existing file stays as it
+    is (``BENCHMARK.json`` only gains entries)."""
+    import jax
+    root = tmp_path / "checkout"
+    shutil.copytree(FIXTURES / "tiny", root)
+    before = {**_files(root / "bench"), **_files(ROOT / "bench")}
+    shutil.copytree(FIXTURES / "arch", root / "bench" / "arch")
+    conf = json.loads((root / "bench/configs/tiny.json").read_text())
+    conf["arch"] = "alternating"
+    conf["model"].update(num_hidden_layers=4, sliding_window=48)
+    (root / "bench/configs/tiny-alternating.json").write_text(
+        json.dumps(conf))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "tiny-alternating", "source": "x",
+                             "file": "bench/configs/tiny-alternating.json",
+                             "reduced": [], "why": "x"})
+    bench["workloads"].append({"name": "tiny-alternating", "config":
+                               "tiny-alternating", "traffic": "tiny",
+                               "chips": 1, "why": "x"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    toy = spec.arch(conf, root)
+    m = toy.sizes("tiny-alternating", conf["model"])
+    assert toy.program(m).pattern == ("local", "global")
+    assert [toy.layer_at(m, r) for r in range(4)] == [
+        ("stages/0/0/", 0), ("stages/0/1/", 0), ("stages/0/0/", 1),
+        ("stages/0/1/", 1)]
+    peak = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11,
+            "hbm_bytes": 1e10}
+    b = spec.load_benchmark(root)
+    r = run.execute(b, "tiny-alternating", 2**31 + 41, 3.0, False,
+                    jax.devices(), root, peak=peak)
+    assert r["correct"], (r["check"], r["readings"])
+    assert r["compiles_in_window"] == 0
+    # the same program judged as if every layer were windowed is not
+    # correct: the cell tells the alternation from the dense model
+    monkeypatch.setattr(toy, "layer", toy.dense.layer)
+    wrong = run.execute(b, "tiny-alternating", 2**31 + 41, 3.0, False,
+                        jax.devices(), root, peak=peak)
+    assert not wrong["correct"], wrong["check"]
+    after = {**_files(root / "bench"), **_files(ROOT / "bench")}
+    for p, data in before.items():
+        assert after[p] == data, p
 
 
 def test_unknown_device_kind_is_an_error():

@@ -4,8 +4,8 @@ hand-made trace."""
 import numpy as np
 import pytest
 
+import spec
 import trace_reduce as tr
-from dims import Dims
 from loop import Record, Sent, Step
 from measure import Run
 from traffic import Arrival
@@ -45,9 +45,10 @@ def test_union_idle_and_programs():
 
 
 def _run(trace=None):
-    m = Dims("x", n_layers=2, d_model=8, n_heads=2, n_kv_heads=1,
-             head_dim=4, d_ff=16, vocab=32, tied=False, norm_eps=1e-5,
-             rope_theta=1e4, max_position=64)
+    dense = spec.arch({"arch": "dense"})
+    m = dense.Dims("x", n_layers=2, d_model=8, n_heads=2, n_kv_heads=1,
+                   head_dim=4, d_ff=16, vocab=32, tied=False, norm_eps=1e-5,
+                   rope_theta=1e4, max_position=64)
     a = Sent(Arrival(0.0, 0, np.zeros(10, np.int32), 3), 0.0, "t0",
              submitted=0.0, token_times=[1.0, 2.0, 3.0])
     b = Sent(Arrival(0.5, 1, np.zeros(6, np.int32), 2), 0.5, "t1",
@@ -56,7 +57,8 @@ def _run(trace=None):
              Step(1.5, 2.0, 2, [(0, 1), (1, 0)], 0.5),
              Step(2.5, 3.0, 2, [(0, 2), (1, 1)], 1.0)]
     rec = Record(0.0, 4.0, [a, b], steps, trace_t0=1.2)
-    return Run(rec=rec, dims=m, deployment={"n_slots": 4, "devices": 1},
+    return Run(rec=rec, arch=dense, dims=m,
+               deployment={"n_slots": 4, "devices": 1},
                chips=1, peak={"bf16_flops_per_s": 1e12,
                               "hbm_bytes_per_s": 1e9, "hbm_bytes": 1e10},
                setup_s=1.0, memory_peak=[5e9],
@@ -88,7 +90,6 @@ def test_work_arithmetic():
 
 
 def test_metric_readers():
-    import spec
     run = _run(_trace())
     read = lambda n: spec.reader(n).read(run)       # noqa: E731
     assert read("tok_s_per_chip") == pytest.approx(5 / 4)
@@ -153,5 +154,4 @@ def test_recorded_chip_trace(tmp_path):
 
 
 def spec_read(name, run):
-    import spec
     return spec.reader(name).read(run)

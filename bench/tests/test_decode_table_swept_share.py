@@ -14,8 +14,9 @@ def _run():
     """A traced window from 10 s to 20 s on the program's clock."""
     rec = Record(9.0, 20.0, [], [Step(10.0, 19.0, 4, [], 0.5)],
                  trace_t0=10.0)
-    return Run(rec=rec, dims=None, deployment={}, chips=1, peak={},
-               setup_s=1.0, memory_peak=[], device_of={}, modules={})
+    return Run(rec=rec, arch=None, dims=None, deployment={}, chips=1,
+               peak={}, setup_s=1.0, memory_peak=[], device_of={},
+               modules={})
 
 
 def _read(monkeypatch, spans):

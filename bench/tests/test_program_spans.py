@@ -4,8 +4,8 @@ import numpy as np
 import pytest
 
 import program_spans as ps
+import spec
 import trace_reduce as tr
-from dims import Dims
 from loop import Record, Sent, Step
 from measure import Run
 from repro.core.spans import Span
@@ -52,9 +52,10 @@ def _spans(jitter=0.0):
 
 
 def _run(trace, admitted=(0.25, 1.25), queued=0):
-    m = Dims("x", n_layers=2, d_model=8, n_heads=2, n_kv_heads=1,
-             head_dim=4, d_ff=16, vocab=32, tied=False, norm_eps=1e-5,
-             rope_theta=1e4, max_position=64)
+    dense = spec.arch({"arch": "dense"})
+    m = dense.Dims("x", n_layers=2, d_model=8, n_heads=2, n_kv_heads=1,
+                   head_dim=4, d_ff=16, vocab=32, tied=False, norm_eps=1e-5,
+                   rope_theta=1e4, max_position=64)
     sent = []
     for i, wait in enumerate(admitted):
         req = type("Req", (), {})()
@@ -69,7 +70,8 @@ def _run(trace, admitted=(0.25, 1.25), queued=0):
     rec = Record(SHIFT - 1.0, SHIFT + 9.6, sent,
                  [Step(SHIFT, SHIFT + 4.5, 2, [], 0.5)],
                  trace_t0=SHIFT - 0.1)
-    return Run(rec=rec, dims=m, deployment={"n_slots": 4, "devices": 2},
+    return Run(rec=rec, arch=dense, dims=m,
+               deployment={"n_slots": 4, "devices": 2},
                chips=2, peak={}, setup_s=1.0, memory_peak=[],
                device_of={}, modules={}, trace=trace)
 
@@ -156,5 +158,4 @@ def test_span_readers(spans):
 
 
 def _read(name, run):
-    import spec
     return spec.reader(name).read(run)

@@ -1,12 +1,13 @@
-"""Seeded random weights for a dense decoder-only configuration, in the
-parameter layout the served program reads.
+"""Seeded random weights in the parameter layout the served program
+reads. The layout itself, and which stacked entry holds a layer, come
+from the configuration's architecture module (``layout``, ``layer_at``).
 
 The program gets all of them from one jitted call on its device, in the
 served dtype. The reference regenerates the same values layer by layer
 (``layer``, ``top``) from the same seed, in float32: it takes nothing the
 program made. Each leaf draws from its own key, derived from the seed and
-the leaf's path, and each layer of a stacked leaf from that key folded
-with the layer index, so one layer can be drawn alone.
+the leaf's path, and each entry of a stacked leaf from that key folded
+with the entry's index, so one layer can be drawn alone.
 """
 from __future__ import annotations
 
@@ -17,31 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dims import Dims
-
-# path -> (shape, scale, stacked over layers)
+# path -> (shape, scale, stacked over the stage's repeats)
 Layout = Dict[str, Tuple[tuple, float, bool]]
-
-
-def layout(m: Dims) -> Layout:
-    d, L, F, V = m.d_model, m.n_layers, m.d_ff, m.vocab
-    kv, g, hd = m.n_kv_heads, m.n_heads // m.n_kv_heads, m.head_dim
-    out = {
-        "embed/tok": ((V, d), d ** -0.5, False),
-        "final_norm": ((d,), 0.1, False),
-        "stages/0/norm1": ((L, d), 0.1, True),
-        "stages/0/norm2": ((L, d), 0.1, True),
-        "stages/0/attn/wq": ((L, d, kv, g, hd), d ** -0.5, True),
-        "stages/0/attn/wk": ((L, d, kv, hd), d ** -0.5, True),
-        "stages/0/attn/wv": ((L, d, kv, hd), d ** -0.5, True),
-        "stages/0/attn/wo": ((L, kv, g, hd, d), (kv * g * hd) ** -0.5, True),
-        "stages/0/mlp/wg": ((L, d, F), d ** -0.5, True),
-        "stages/0/mlp/wu": ((L, d, F), d ** -0.5, True),
-        "stages/0/mlp/wd": ((L, F, d), F ** -0.5, True),
-    }
-    if not m.tied:
-        out["head"] = ((d, V), d ** -0.5, False)
-    return out
 
 
 def seed_key(seed: int):
@@ -70,8 +48,19 @@ def _leaf(key, path, shape, scale, stacked, dtype):
     return jax.vmap(lambda lk: _draw(lk, shape[1:], scale, dtype))(layer_keys)
 
 
+def _tuples(node):
+    """Dicts keyed '0', '1', ... (the stages, and the positions of a
+    pattern stage) -> tuples, as the program's tree holds them."""
+    if not isinstance(node, dict):
+        return node
+    node = {k: _tuples(v) for k, v in node.items()}
+    if all(k.isdigit() for k in node):
+        return tuple(node[str(i)] for i in range(len(node)))
+    return node
+
+
 def _nest(flat: dict) -> dict:
-    """'stages/0/attn/wq' paths -> the program's tree (stages a tuple)."""
+    """'stages/0/attn/wq' paths -> the program's tree."""
     tree: dict = {}
     for path, v in flat.items():
         node = tree
@@ -79,10 +68,7 @@ def _nest(flat: dict) -> dict:
         for p in parents:
             node = node.setdefault(p, {})
         node[last] = v
-    if "stages" in tree:
-        st = tree["stages"]
-        tree["stages"] = tuple(st[str(i)] for i in range(len(st)))
-    return tree
+    return _tuples(tree)
 
 
 def make_params(lay: Layout, seed: int, device, dtype=jnp.bfloat16):
@@ -113,14 +99,17 @@ def _draw_f32_impl(key, shape, scale):
 _draw_f32 = jax.jit(_draw_f32_impl, static_argnums=(1, 2))
 
 
-def layer(lay: Layout, seed: int, r: int) -> dict:
-    """Layer ``r``'s weights, float32 values of the served bfloat16 ones,
-    keyed by the layer-relative path ('attn/wq', 'norm1', ...)."""
+def layer(lay: Layout, seed: int, prefix: str, index: int) -> dict:
+    """One layer's weights: entry ``index`` of the stacked leaves under
+    ``prefix`` (a stage, or a position in a pattern stage: 'stages/0/'),
+    float32 values of the served bfloat16 ones, keyed by the path below
+    ``prefix`` ('attn/wq', 'norm1', ...)."""
     base = seed_key(seed)
-    return {p.split("/", 2)[2]: _draw_f32(
+    return {p[len(prefix):]: _draw_f32(
                 jax.random.fold_in(jax.random.fold_in(base, _path_id(p)),
-                                   np.uint32(r)), shape[1:], scale)
-            for p, (shape, scale, stacked) in lay.items() if stacked}
+                                   np.uint32(index)), shape[1:], scale)
+            for p, (shape, scale, stacked) in lay.items()
+            if stacked and p.startswith(prefix)}
 
 
 def top(lay: Layout, seed: int) -> dict:
