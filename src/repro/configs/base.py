@@ -54,6 +54,15 @@ class SSMConfig:
     n_groups: int = 1
     chunk: int = 256              # SSD chunk length
     a_init_range: Tuple[float, float] = (1.0, 16.0)
+    # granite-4.0-h: every layer, Mamba-2 ones included, carries the
+    # dense MLP; the embedding is scaled, each branch's output is scaled
+    # before it joins the residual stream, and the logits are divided.
+    # They live here, not on ModelConfig, so that attention-only configs
+    # (ssm=None) keep their exact field set.
+    site_mlp: bool = False
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -203,6 +212,8 @@ class ModelConfig:
         for i, kind in enumerate(self.layer_kinds()):
             if kind == MIXER_SSM:
                 total += self._ssm_params()
+                if self.ssm.site_mlp:
+                    total += 3 * d * self.d_ff
             elif kind == MIXER_SHARED_ATTN:
                 if not shared_counted:   # zamba2: one weight set reused
                     total += self._attn_params() + 3 * d * self.d_ff

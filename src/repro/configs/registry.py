@@ -11,7 +11,7 @@ from repro.configs.base import ModelConfig
 ARCH_IDS = (
     "gemma3-1b", "gemma2-9b", "phi3-mini-3.8b", "smollm-135m",
     "mamba2-370m", "deepseek-v2-lite-16b", "qwen3-moe-30b-a3b",
-    "zamba2-7b", "whisper-tiny", "llava-next-34b",
+    "zamba2-7b", "whisper-tiny", "llava-next-34b", "granite-4.0-h-micro",
 )
 
 _MODULES = {
@@ -25,6 +25,7 @@ _MODULES = {
     "zamba2-7b": "zamba2_7b",
     "whisper-tiny": "whisper_tiny",
     "llava-next-34b": "llava_next_34b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 _cache: Dict[str, ModelConfig] = {}

@@ -209,7 +209,7 @@ def attn_forward(p, x, positions, opts: AttnOpts,
           and kreg.check_flash_blocks(S, opts.flash_block_q,
                                       opts.flash_block_k) is None):
         y = _flash_kernel_attend(q, k, v, opts, fmode)
-    elif qc and S > qc and S % qc == 0:
+    elif qc and S > qc:
         y = _chunked_attend(q, k, v, positions, k_pos, k_valid, opts)
     else:
         mask = _causal_mask(positions, k_pos, opts.window, opts.causal, k_valid)
@@ -246,9 +246,17 @@ def _gather_seq(t):
 
 
 def _chunked_attend(q, k, v, q_pos, k_pos, k_valid, opts: AttnOpts):
-    """Scan over query chunks; local layers slice keys to the window."""
-    B, S = q.shape[:2]
+    """Scan over query chunks; local layers slice keys to the window. A
+    length that is no multiple of the chunk pads the queries to one (the
+    padded rows are dropped), so no (S, S) score matrix is ever built."""
+    S = q.shape[1]
     qc = opts.q_chunk
+    if S % qc:
+        extra = -S % qc
+        q = jnp.pad(q, [(0, 0), (0, extra)] + [(0, 0)] * (q.ndim - 2))
+        q_pos = jnp.pad(q_pos, [(0, 0), (0, extra)], mode="edge")
+        return _chunked_attend(q, k, v, q_pos, k_pos, k_valid, opts)[:, :S]
+    B = q.shape[0]
     n_chunks = S // qc
     w = opts.window
     if opts.attn_tp == "heads":

@@ -22,6 +22,7 @@ class SSMOpts:
     d_model: int
     cfg: SSMConfig
     tp: bool = True          # False = pure-DP mode, no TP constraints
+    eps: float = 1e-6        # the gated RMSNorm's epsilon
 
     @property
     def d_inner(self) -> int:
@@ -52,6 +53,7 @@ def init_ssm(key, opts: SSMOpts, dtype=jnp.float32):
         "dt_bias": jnp.zeros((H,), jnp.float32),
         "A_log": jnp.log(a),
         "D": jnp.ones((H,), jnp.float32),
+        # gated RMSNorm weight, stored as w - 1 and applied as 1 + w
         "norm": jnp.zeros((d_in,), dtype),
         "out_proj": jax.random.normal(k3, (d_in, d), dtype) * d_in ** -0.5,
     }
@@ -177,13 +179,31 @@ def ssd_scan(xs, dt, A, Bm, Cm, D, chunk: int, init_state=None):
     return y, state
 
 
-def ssm_forward(p, x, opts: SSMOpts, init_state=None):
-    """Full-sequence Mamba2 block. Returns (y, (ssd_state, conv_tail))."""
+def _conv_tail(xbc, n, k: int):
+    """The ``k`` conv inputs before position ``n`` (B,) of each row, zeros
+    where a row has fewer: what the decode conv cache holds after ``n``
+    positions."""
+    xp = jnp.pad(xbc, [(0, 0), (k, 0), (0, 0)])
+    return jax.vmap(lambda r, i: jax.lax.dynamic_slice_in_dim(r, i, k))(
+        xp, n)
+
+
+def ssm_forward(p, x, opts: SSMOpts, init_state=None, valid=None):
+    """Full-sequence Mamba2 block. Returns (y, (ssd_state, conv_tail)).
+
+    ``valid`` (B, S) bool marks each row's real positions, a prefix: the
+    rest is padding, which runs with ``dt = 0`` and ``x = 0`` so that it
+    leaves the state as the real positions left it, and the conv tail is
+    taken at each row's real length."""
     Bsz, S, d = x.shape
     c = opts.cfg
     zxbcdt = jnp.einsum("bsd,de->bse", x, p["in_proj"].astype(x.dtype))
     z, xbc, dt = _split_proj(zxbcdt, opts)
-    conv_tail = xbc[:, -(c.d_conv - 1):, :]          # decode conv cache
+    if valid is None:
+        conv_tail = xbc[:, -(c.d_conv - 1):, :]      # decode conv cache
+    else:
+        conv_tail = _conv_tail(xbc, jnp.sum(valid, axis=1, dtype=jnp.int32),
+                               c.d_conv - 1)
     if opts.tp:
         xbc = _shard_tail(xbc, 1)                    # channels over model
     xbc = jax.nn.silu(_causal_conv(xbc, p["conv_w"], p["conv_b"]))
@@ -193,10 +213,13 @@ def ssm_forward(p, x, opts: SSMOpts, init_state=None):
     if opts.tp:
         xs = _shard_tail(xs, 2)                      # ssd heads over model
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
+    if valid is not None:
+        dt = jnp.where(valid[..., None], dt, 0.0)
+        xs = jnp.where(valid[..., None, None], xs, 0)
     A = -jnp.exp(p["A_log"])
     y, state = ssd_scan(xs, dt, A, Bm, Cm, p["D"], c.chunk, init_state)
     y = y.reshape(Bsz, S, opts.d_inner)
-    y = rms_norm(y * jax.nn.silu(z), p["norm"], plus_one=False)
+    y = rms_norm(y * jax.nn.silu(z), p["norm"], opts.eps)
     out = jnp.einsum("bse,ed->bsd", y, p["out_proj"].astype(x.dtype))
     return out, (state, conv_tail)
 
@@ -238,6 +261,6 @@ def ssm_decode(p, x, cache, opts: SSMOpts):
     y = jnp.einsum("bhpn,bhn->bhp", state, Ch.astype(jnp.float32))
     y = y + p["D"][None, :, None] * xs[:, 0].astype(jnp.float32)
     y = y.reshape(Bsz, 1, opts.d_inner).astype(x.dtype)
-    y = rms_norm(y * jax.nn.silu(z), p["norm"], plus_one=False)
+    y = rms_norm(y * jax.nn.silu(z), p["norm"], opts.eps)
     out = jnp.einsum("bse,ed->bsd", y, p["out_proj"].astype(x.dtype))
     return out, {"state": state, "conv": new_conv}

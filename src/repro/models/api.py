@@ -72,11 +72,12 @@ class Model:
             return encdec.make_encdec_caches(self.cfg, batch, max_len)
         return lm.make_decode_caches(self.cfg, batch, max_len)
 
-    def make_paged_caches(self, n_pages: int, page_size: int):
+    def make_paged_caches(self, n_pages: int, page_size: int,
+                          n_slots: int = 0):
         """Empty paged KV pool (see ``models.stages.init_paged_cache``)."""
         if self.cfg.family == "audio":
             raise ValueError("paged caches support decoder-only LMs")
-        return lm.make_paged_caches(self.cfg, n_pages, page_size)
+        return lm.make_paged_caches(self.cfg, n_pages, page_size, n_slots)
 
 
 def get_model(cfg: ModelConfig) -> Model:

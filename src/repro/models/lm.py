@@ -55,6 +55,8 @@ def _embed_tokens(cfg, params, tokens, patches=None):
     with jax.named_scope("rc3e.embed"):
         x = embed(params["embed"], tokens, scale_by_dim=cfg.embed_scale)
         x = x.astype(_dtype(cfg))
+        if cfg.ssm is not None and cfg.ssm.embedding_multiplier != 1.0:
+            x = x * cfg.ssm.embedding_multiplier
         if patches is not None:
             x = jnp.concatenate([patches.astype(x.dtype), x], axis=1)
     return x
@@ -71,13 +73,15 @@ def lm_forward(cfg: ModelConfig, params, tokens, patches=None,
     x = _embed_tokens(cfg, params, tokens, patches)
     pos = _positions(x)
     x, _, aux = apply_stages(cfg, params, x, pos, mode="train", remat=remat)
-    h = rms_norm(x, params["final_norm"])
+    h = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return h, aux
 
 
 def lm_logits(cfg: ModelConfig, params, h):
     w = params["embed"]["tok"].T if cfg.tie_embeddings else params["head"]
     out = jnp.einsum("bsd,dv->bsv", h, w.astype(h.dtype))
+    if cfg.ssm is not None and cfg.ssm.logits_scaling != 1.0:
+        out = out / cfg.ssm.logits_scaling
     return softcap(out, cfg.final_softcap)
 
 
@@ -86,21 +90,28 @@ def lm_prefill(cfg: ModelConfig, params, tokens, max_len: int, patches=None,
     """Run the prompt, building decode caches sized ``max_len``.
 
     ``clamp_window=False`` builds full-length (non-ring) caches even for
-    windowed sites — the layout the paged page-splice expects."""
+    windowed sites — the layout the paged page-splice expects. With SSM
+    sites a row may end in padding, marked by negative token ids: the
+    recurrent state and conv tail are taken at the real length (the
+    padding embeds as token 0)."""
+    valid = None
+    if cfg.ssm is not None:
+        valid = tokens >= 0
+        tokens = jnp.maximum(tokens, 0)
     x = _embed_tokens(cfg, params, tokens, patches)
     pos = _positions(x)
     x, caches, _ = apply_stages(cfg, params, x, pos, mode="prefill",
                                 max_len=max_len, cache_dtype=_dtype(cfg),
-                                clamp_window=clamp_window)
+                                clamp_window=clamp_window, valid=valid)
     with jax.named_scope("rc3e.head"):
-        h = rms_norm(x, params["final_norm"])
+        h = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return h, caches
 
 
 def _head(cfg: ModelConfig, params, x):
     """Final norm and logits of a decode step."""
     with jax.named_scope("rc3e.head"):
-        h = rms_norm(x, params["final_norm"])
+        h = rms_norm(x, params["final_norm"], cfg.norm_eps)
         return lm_logits(cfg, params, h)
 
 
@@ -130,6 +141,8 @@ def make_decode_caches(cfg: ModelConfig, batch: int, max_len: int):
     return init_cache(cfg, batch, max_len, _dtype(cfg))
 
 
-def make_paged_caches(cfg: ModelConfig, n_pages: int, page_size: int):
-    """Empty paged KV pool (shared across every serving slot)."""
-    return init_paged_cache(cfg, n_pages, page_size, _dtype(cfg))
+def make_paged_caches(cfg: ModelConfig, n_pages: int, page_size: int,
+                      n_slots: int = 0):
+    """Empty paged KV pool (shared across every serving slot), with a
+    recurrent-state row per slot for each SSM site."""
+    return init_paged_cache(cfg, n_pages, page_size, _dtype(cfg), n_slots)

@@ -60,6 +60,8 @@ class Stage:
 def _make_site(cfg: ModelConfig, i: int) -> LayerSite:
     mixer = cfg.layer_kinds()[i]
     if mixer == MIXER_SSM:
+        if cfg.ssm.site_mlp:
+            return LayerSite(mixer=mixer, mlp="dense", d_ff=cfg.d_ff)
         return LayerSite(mixer=mixer, mlp="none")
     theta = cfg.rope_theta
     window = 0
@@ -134,7 +136,7 @@ def mla_opts(cfg: ModelConfig) -> MLAOpts:
 
 def ssm_opts(cfg: ModelConfig) -> SSMOpts:
     return SSMOpts(d_model=cfg.d_model, cfg=cfg.ssm,
-                   tp=cfg.tp_mode == "tp")
+                   tp=cfg.tp_mode == "tp", eps=cfg.norm_eps)
 
 
 def moe_opts(cfg: ModelConfig) -> MoEOpts:
@@ -147,6 +149,12 @@ def moe_opts(cfg: ModelConfig) -> MoEOpts:
 
 def _init_site(cfg: ModelConfig, site: LayerSite, key, dtype):
     if site.mixer == MIXER_SSM:
+        if site.mlp == "dense":
+            k1, k2 = jax.random.split(key)
+            return {"ssm": init_ssm(k1, ssm_opts(cfg), dtype),
+                    "norm1": jnp.zeros((cfg.d_model,), dtype),
+                    "norm2": jnp.zeros((cfg.d_model,), dtype),
+                    "mlp": init_mlp(k2, cfg.d_model, site.d_ff, dtype)}
         k1, = jax.random.split(key, 1)
         return {"ssm": init_ssm(k1, ssm_opts(cfg), dtype),
                 "norm1": jnp.zeros((cfg.d_model,), dtype)}
@@ -238,21 +246,30 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype):
     return tuple(out)
 
 
-def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int, dtype):
+def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int, dtype,
+                     n_slots: int = 0):
     """Empty paged KV pool pytree mirroring the stage structure: every
     attention site gets (n_pages, page_size, kv, hd) pool tensors instead
     of per-sequence (batch, L) rows. One logical page allocates the same
     physical row in every layer's pool, so a single block table per
     sequence addresses the whole stack. Windowed sites share the layout
-    (the decode mask enforces the window); SSM/MLA archs have no paged
-    form."""
-    if cfg.ssm is not None or cfg.mla is not None:
+    (the decode mask enforces the window). Every SSM site gets a row of
+    recurrent state per serving slot (``n_slots``): ``{"state" (n_slots,
+    H, P, N) f32, "conv" (n_slots, d_conv - 1, channels)}``, beside the
+    pages in the same tree. MLA archs have no paged form."""
+    if cfg.mla is not None:
         raise ValueError("paged KV caches support attention-family models "
-                         "(SSM state and MLA latents are not paged)")
+                         "(MLA latents are not paged)")
+    if cfg.ssm is not None and n_slots < 1:
+        raise ValueError("a paged cache with SSM sites needs n_slots")
 
     def stacked(site, n):
-        one = init_paged_kv_pool(n_pages, page_size, attn_opts(cfg, site),
-                                 dtype, quant=cfg.kv_quant)
+        if site.mixer == MIXER_SSM:
+            one = init_ssm_cache(n_slots, ssm_opts(cfg), dtype)
+        else:
+            one = init_paged_kv_pool(n_pages, page_size,
+                                     attn_opts(cfg, site), dtype,
+                                     quant=cfg.kv_quant)
         return jax.tree.map(
             lambda a: jnp.broadcast_to(a, (n,) + a.shape).copy(), one)
 
@@ -272,6 +289,8 @@ def paged_sweep_layers(cfg: ModelConfig, pool, batch: int, n_cols: int):
     out = []
     for st, c in zip(plan_stages(cfg), pool):
         for site, leaf in zip(st.sites, (c,) if st.kind == "run" else c):
+            if site.mixer == MIXER_SSM:
+                continue
             n, _, ps, kv, hd = leaf["k"].shape
             out.append((site.window,
                         sweep_chunk_cols(batch, n_cols, ps, kv, hd,
@@ -282,6 +301,27 @@ def paged_sweep_layers(cfg: ModelConfig, pool, batch: int, n_cols: int):
 # ---------------------------------------------------------------------------
 # Site application
 # ---------------------------------------------------------------------------
+
+def _residual(cfg, y):
+    """A branch's output as it joins the residual stream."""
+    r = cfg.ssm.residual_multiplier if cfg.ssm is not None else 1.0
+    return y if r == 1.0 else y * r
+
+
+def _ssm_site(cfg, site, p, x, mixer):
+    """An SSM site: ``mixer(h) -> (y, cache)`` on the normed input, then
+    the site's MLP where it has one. Returns (x', cache, aux)."""
+    aux = jnp.zeros((), jnp.float32)
+    h = rms_norm(x, p["norm1"], cfg.norm_eps)
+    with jax.named_scope("rc3e.ssm"):
+        y, cache = mixer(h)
+    x = x + _residual(cfg, y)
+    if site.mlp != "none":
+        h = rms_norm(x, p["norm2"], cfg.norm_eps)
+        y, aux = _mlp(cfg, site, p, h, x, aux)
+        x = x + _residual(cfg, y)
+    return x, cache, aux
+
 
 def _mlp(cfg, site, pp, h, x, aux):
     """A site's feed-forward half: (y, aux)."""
@@ -294,26 +334,27 @@ def _mlp(cfg, site, pp, h, x, aux):
 
 
 def _apply_site_full(cfg, site, p, shared, x, positions, mode, max_len, dtype,
-                     clamp_window: bool = True):
+                     clamp_window: bool = True, valid=None):
     """Full-sequence site application.
 
     mode: "train" (no cache) | "prefill" (returns filled cache).
     ``clamp_window=False`` builds full-``max_len`` caches even for windowed
-    sites (no ring) — the layout the paged splice expects.
+    sites (no ring) — the layout the paged splice expects. ``valid``
+    (B, S) marks the real positions of padded rows: an SSM site's state
+    and conv tail are taken at the real length.
     Returns (x', cache_or_None, aux).
     """
     aux = jnp.zeros((), jnp.float32)
     if site.mixer == MIXER_SSM:
-        h = rms_norm(x, p["norm1"])
-        y, (state, conv_tail) = ssm_forward(p["ssm"], h, ssm_opts(cfg))
-        x = x + y
-        cache = None
-        if mode == "prefill":
-            cache = {"state": state, "conv": conv_tail}
-        return x, cache, aux
+        def mixer(h):
+            y, (state, conv_tail) = ssm_forward(p["ssm"], h, ssm_opts(cfg),
+                                                valid=valid)
+            return y, {"state": state, "conv": conv_tail}
+        x, cache, aux = _ssm_site(cfg, site, p, x, mixer)
+        return x, cache if mode == "prefill" else None, aux
 
     pp = shared if site.mixer == MIXER_SHARED_ATTN else p
-    h = rms_norm(x, pp["norm1"])
+    h = rms_norm(x, pp["norm1"], cfg.norm_eps)
     with jax.named_scope("rc3e.attn"):
         if cfg.mla is not None:
             y, (c_kv, k_rope) = mla_forward(pp["attn"], h, positions,
@@ -322,14 +363,14 @@ def _apply_site_full(cfg, site, p, shared, x, positions, mode, max_len, dtype,
             y, (k, v) = attn_forward(pp["attn"], h, positions,
                                      attn_opts(cfg, site))
     if cfg.post_norm:
-        y = rms_norm(y, p["norm1_post"])
-    x = x + y
+        y = rms_norm(y, p["norm1_post"], cfg.norm_eps)
+    x = x + _residual(cfg, y)
 
-    h = rms_norm(x, pp["norm2"])
+    h = rms_norm(x, pp["norm2"], cfg.norm_eps)
     y, aux = _mlp(cfg, site, pp, h, x, aux)
     if cfg.post_norm:
-        y = rms_norm(y, p["norm2_post"])
-    x = x + y
+        y = rms_norm(y, p["norm2_post"], cfg.norm_eps)
+    x = x + _residual(cfg, y)
 
     cache = None
     if mode == "prefill":
@@ -348,32 +389,44 @@ def _apply_site_full(cfg, site, p, shared, x, positions, mode, max_len, dtype,
 
 def _apply_site_decode_paged(cfg, site, p, shared, x, positions, cache,
                              block_tables):
-    """Decode one site against its paged pool (block-table indirection)."""
+    """Decode one site against its paged pool (block-table indirection).
+    An SSM site steps every slot's state row and keeps the old row where
+    the slot is inactive (position -1)."""
+    if site.mixer == MIXER_SSM:
+        live = positions[:, 0] >= 0
+
+        def mixer(h):
+            y, new = ssm_decode(p["ssm"], h, cache, ssm_opts(cfg))
+            return y, jax.tree.map(
+                lambda n, o: jnp.where(
+                    live.reshape((-1,) + (1,) * (o.ndim - 1)), n, o),
+                new, cache)
+        return _ssm_site(cfg, site, p, x, mixer)
     aux = jnp.zeros((), jnp.float32)
     pp = shared if site.mixer == MIXER_SHARED_ATTN else p
-    h = rms_norm(x, pp["norm1"])
+    h = rms_norm(x, pp["norm1"], cfg.norm_eps)
     with jax.named_scope("rc3e.attn"):
         y, cache = attn_decode_paged(pp["attn"], h, positions, cache,
                                      block_tables, attn_opts(cfg, site))
     if cfg.post_norm:
-        y = rms_norm(y, p["norm1_post"])
-    x = x + y
-    h = rms_norm(x, pp["norm2"])
+        y = rms_norm(y, p["norm1_post"], cfg.norm_eps)
+    x = x + _residual(cfg, y)
+    h = rms_norm(x, pp["norm2"], cfg.norm_eps)
     y, aux = _mlp(cfg, site, pp, h, x, aux)
     if cfg.post_norm:
-        y = rms_norm(y, p["norm2_post"])
-    return x + y, cache, aux
+        y = rms_norm(y, p["norm2_post"], cfg.norm_eps)
+    return x + _residual(cfg, y), cache, aux
 
 
 def _apply_site_decode(cfg, site, p, shared, x, positions, cache):
-    aux = jnp.zeros((), jnp.float32)
     if site.mixer == MIXER_SSM:
-        h = rms_norm(x, p["norm1"])
-        y, cache = ssm_decode(p["ssm"], h, cache, ssm_opts(cfg))
-        return x + y, cache, aux
+        return _ssm_site(cfg, site, p, x,
+                         lambda h: ssm_decode(p["ssm"], h, cache,
+                                              ssm_opts(cfg)))
+    aux = jnp.zeros((), jnp.float32)
 
     pp = shared if site.mixer == MIXER_SHARED_ATTN else p
-    h = rms_norm(x, pp["norm1"])
+    h = rms_norm(x, pp["norm1"], cfg.norm_eps)
     with jax.named_scope("rc3e.attn"):
         if cfg.mla is not None:
             y, cache = mla_decode(pp["attn"], h, positions, cache,
@@ -382,13 +435,13 @@ def _apply_site_decode(cfg, site, p, shared, x, positions, cache):
             y, cache = attn_decode(pp["attn"], h, positions, cache,
                                    attn_opts(cfg, site))
     if cfg.post_norm:
-        y = rms_norm(y, p["norm1_post"])
-    x = x + y
-    h = rms_norm(x, pp["norm2"])
+        y = rms_norm(y, p["norm1_post"], cfg.norm_eps)
+    x = x + _residual(cfg, y)
+    h = rms_norm(x, pp["norm2"], cfg.norm_eps)
     y, aux = _mlp(cfg, site, pp, h, x, aux)
     if cfg.post_norm:
-        y = rms_norm(y, p["norm2_post"])
-    return x + y, cache, aux
+        y = rms_norm(y, p["norm2_post"], cfg.norm_eps)
+    return x + _residual(cfg, y), cache, aux
 
 
 # ---------------------------------------------------------------------------
@@ -432,13 +485,14 @@ def _gather_act(x):
 def apply_stages(cfg: ModelConfig, params, x, positions, *,
                  mode: str = "train", caches=None, max_len: int = 0,
                  remat: bool = False, cache_dtype=None, block_tables=None,
-                 clamp_window: bool = True):
+                 clamp_window: bool = True, valid=None):
     """Run all stages. mode: train | prefill | decode.
 
     ``block_tables`` (B, nb) switches decode to the paged-pool path (caches
     must come from ``init_paged_cache``). ``clamp_window=False`` makes
     prefill build full-length caches for windowed sites (paged splice
-    layout). Returns (x, new_caches_or_None, aux_sum).
+    layout). ``valid`` (B, S): the real positions of a padded prefill.
+    Returns (x, new_caches_or_None, aux_sum).
     """
     stages = plan_stages(cfg)
     shared = params.get("shared")
@@ -473,7 +527,7 @@ def apply_stages(cfg: ModelConfig, params, x, positions, *,
                         xx = _gather_act(xx)
                     xx, c_i, a = _apply_site_full(cfg, site, p_i, shared, xx,
                                                   positions, mode, max_len,
-                                                  dtype, clamp_window)
+                                                  dtype, clamp_window, valid)
                     if use_sp:
                         xx = _seq_shard(xx)
                     return (xx, aux + a), c_i
@@ -504,7 +558,7 @@ def apply_stages(cfg: ModelConfig, params, x, positions, *,
                     for site_i, p_i in zip(sites, ps):
                         xx, c_i, a = _apply_site_full(
                             cfg, site_i, p_i, shared, xx, positions, mode,
-                            max_len, dtype, clamp_window)
+                            max_len, dtype, clamp_window, valid)
                         aux = aux + a
                         outc.append(c_i)
                     if use_sp:

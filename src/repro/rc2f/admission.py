@@ -88,7 +88,10 @@ class ServiceQuota:
 DEFAULT_QUOTAS: Dict[str, ServiceQuota] = {
     # RSaaS tenants own whole devices; request limits are irrelevant there
     "rsaas": ServiceQuota(max_slots_per_tenant=4, max_inflight_requests=256),
-    "raas": ServiceQuota(max_slots_per_tenant=2, max_inflight_requests=64),
+    # RAaaS tenants serve long contexts (retrieval, documents): prompts up
+    # to 8192 tokens, within the engine's own max_len
+    "raas": ServiceQuota(max_slots_per_tenant=2, max_inflight_requests=64,
+                         max_prompt_tokens=8192),
     # BAaaS is the shared serving pool: tight per-tenant ceilings so one
     # tenant cannot monopolize the provider's device
     "baas": ServiceQuota(max_slots_per_tenant=2, max_inflight_requests=16,

@@ -44,7 +44,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.analysis.lifecycle import sanitizer
 from repro.configs.base import GeometryConfig
-from repro.core.device_db import DeviceState, SliceState
+from repro.core.device_db import MAX_SLOTS, DeviceState, SliceState
 from repro.core.elastic import ElasticController
 from repro.core.hypervisor import Hypervisor
 from repro.core.spans import span
@@ -133,9 +133,10 @@ class GatewayFleet:
         # fail fast, before any session can allocate: lazy engine creation
         # must never be the first place this surfaces (it would strand an
         # admitted tenant and its vSlice)
-        if model.cfg.ssm is not None:
-            raise ValueError("GatewayFleet serves attention-family models; "
-                             "use jit_serve_step for SSM archs")
+        if model.cfg.ssm is not None and not paged:
+            raise ValueError("a model with SSM sites is served by paged "
+                             "engines (paged=True), which keep a state "
+                             "row per slot")
         if paged and model.cfg.mla is not None:
             raise ValueError("paged KV caches support plain-attention "
                              "models (MLA latents are not paged)")
@@ -277,7 +278,7 @@ class GatewayFleet:
                 else default_pool_pages(n_slots, max_blocks)
             decode_fn = make_paged_serve_step(model)
             example[1] = jax.eval_shape(
-                lambda: model.make_paged_caches(pages, page_size))
+                lambda: model.make_paged_caches(pages, page_size, n_slots))
             example.append(jnp.zeros((n_slots, max_blocks), jnp.int32))
         else:
             decode_fn = make_serve_step(model)
@@ -385,15 +386,22 @@ class GatewayFleet:
     # ------------------------------------------------------------------
     # Tenant sessions
     # ------------------------------------------------------------------
+    def _engine_slots(self, slots: int) -> int:
+        """The engine slots a session of ``slots`` vSlice slots may hold at
+        once: its slots, or, on an engine wider than a device's
+        ``MAX_SLOTS`` vSlice slots, the same share of the engine's."""
+        return max(slots, slots * self.n_slots // MAX_SLOTS)
+
     def _session_page_grant(self, slots: int) -> int:
         """A k-slot session's share of one engine's page pool (the vSlice
-        memory dimension)."""
+        memory dimension), in proportion to its engine slots."""
         if not self.paged:
             return 0
         pages = self.cache_pages if self.cache_pages is not None \
             else default_pool_pages(self.n_slots,
                                     self.max_len // self.page_size)
-        return max(1, (pages - 1) * slots // self.n_slots)
+        return max(1, (pages - 1) * self._engine_slots(slots)
+                   // self.n_slots)
 
     def open_session(self, tenant: str, slots: int = 1,
                      service_model: str = "baas") -> TenantSession:
@@ -412,7 +420,7 @@ class GatewayFleet:
                 vs.slice_id, bundle.decode_fn,
                 bundle.example_on(self.jax_device(vs.device_id)),
                 static_desc=bundle.desc, geometry=bundle.geometry)
-            engine.set_tenant_share(tenant, slots)
+            engine.set_tenant_share(tenant, self._engine_slots(slots))
             engine.set_tenant_weight(tenant, slots)
             if self.paged:
                 engine.set_tenant_pages(tenant, vs.cache_pages or None)
@@ -713,7 +721,8 @@ class GatewayFleet:
             # (shares set below); the hook exports snapshots, marks the
             # source draining, and schedules the drain + adoption a few
             # ticks out (export-generation check / replay fallback there).
-            target.set_tenant_share(sess.tenant, sess.slots)
+            target.set_tenant_share(sess.tenant,
+                                    self._engine_slots(sess.slots))
             target.set_tenant_weight(sess.tenant, sess.slots)
             if target.paged:
                 vs = self.hv.db.find_slice(new)
@@ -740,7 +749,7 @@ class GatewayFleet:
             source.set_tenant_share(sess.tenant, None)
             source.set_tenant_weight(sess.tenant, None)
             source.set_tenant_pages(sess.tenant, None)
-        target.set_tenant_share(sess.tenant, sess.slots)
+        target.set_tenant_share(sess.tenant, self._engine_slots(sess.slots))
         target.set_tenant_weight(sess.tenant, sess.slots)
         if target.paged:
             vs = self.hv.db.find_slice(new)
@@ -866,7 +875,7 @@ class GatewayFleet:
                 vs.slice_id, SliceState.CONFIGURED,
                 program=self._bundle_for(vs.device_id).fingerprint
                 or self.program_fingerprint)
-            target.set_tenant_share(tenant, vs.slots)
+            target.set_tenant_share(tenant, self._engine_slots(vs.slots))
             target.set_tenant_weight(tenant, vs.slots)
             if self.paged:
                 target.set_tenant_pages(tenant, vs.cache_pages or None)

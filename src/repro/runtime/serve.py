@@ -109,22 +109,43 @@ def _splice_slot(full, one, slot):
         lambda f, o: f.at[:, slot].set(o[:, 0].astype(f.dtype)), full, one)
 
 
+# keys of the per-slot recurrent-state leaves of a paged cache tree
+# (``init_paged_cache``): (L, n_slots, ...) rows beside the (L, P, ps, ...)
+# pages. The page programs below pass them through untouched; the state
+# has programs of its own (``_write_slot_state``, ``_scrub_slot_state``).
+STATE_KEYS = ("state", "conv")
+
+
+def _is_state(path) -> bool:
+    return getattr(path[-1], "key", None) in STATE_KEYS
+
+
+def _page_leaves(tree) -> list:
+    """The page leaves of a paged cache tree or of a page payload."""
+    return [leaf for path, leaf in
+            jax.tree_util.tree_flatten_with_path(tree)[0]
+            if not _is_state(path)]
+
+
 @functools.partial(jax.jit, donate_argnums=(0,), static_argnames=("start",))
 def _splice_pages(pool, one, pages, start: int):
     """Scatter a batch-1 full-length prefill cache into pool pages: block
     ``start + i`` of the context lands in page ``pages[i]``. Pool leaves
     are (L, P, ps, ...), prefill leaves (L, 1, max_len, ...); the pool tree
-    is donated (only the touched pages change)."""
+    is donated (only the touched pages change). Recurrent-state leaves
+    are left as they are."""
     nb = pages.shape[0]
 
-    def put(pl_leaf, d_leaf):
+    def put(path, pl_leaf, d_leaf):
+        if _is_state(path):
+            return pl_leaf
         ps = pl_leaf.shape[2]
         seg = jax.lax.dynamic_slice_in_dim(d_leaf[:, 0], start * ps, nb * ps,
                                            axis=1)
         seg = seg.reshape((d_leaf.shape[0], nb, ps) + d_leaf.shape[3:])
         return pl_leaf.at[:, pages].set(seg.astype(pl_leaf.dtype))
 
-    return jax.tree.map(put, pool, one)
+    return jax.tree_util.tree_map_with_path(put, pool, one)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -153,6 +174,8 @@ def _scrub_pool_pages(pool, pages):
     engine flush, not one per page."""
     def scrub(path, leaf):
         key = getattr(path[-1], "key", None)
+        if key in STATE_KEYS:
+            return leaf
         if key == "pos":
             return leaf.at[:, pages].set(-1)
         if key in ("k_scale", "v_scale"):
@@ -165,7 +188,28 @@ def _scrub_pool_pages(pool, pages):
 def _copy_page(pool, src, dst):
     """Copy-on-write detach: duplicate page ``src`` into ``dst`` across
     every layer's pool (leaves are (L, P, ps, ...); axis 1 is the page)."""
-    return jax.tree.map(lambda a: a.at[:, dst].set(a[:, src]), pool)
+    return jax.tree_util.tree_map_with_path(
+        lambda path, a: a if _is_state(path) else a.at[:, dst].set(a[:, src]),
+        pool)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _write_slot_state(caches, one, slot):
+    """Write the recurrent state of a batch-1 tree (a prefill's caches, or
+    a hand-off payload) into row ``slot`` of every SSM site; the pages are
+    left as they are."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, c, o: c.at[:, slot].set(o[:, 0].astype(c.dtype))
+        if _is_state(path) else c, caches, one)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _scrub_slot_state(caches, slots):
+    """Zero-on-free for recurrent state: zero rows ``slots`` (an
+    (n_slots,) vector, padded by repeating a slot) of every SSM site."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, c: c.at[:, slots].set(0) if _is_state(path) else c,
+        caches)
 
 
 @jax.jit
@@ -183,10 +227,12 @@ def _argmax_tokens(logits):
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _import_pages(pool, payload, pages):
     """Scatter a migrated request's page payload (leaves (L, nb, ps, ...))
-    into freshly allocated pages of this engine's pool."""
-    return jax.tree.map(
-        lambda pl_leaf, seg: pl_leaf.at[:, pages].set(
-            seg.astype(pl_leaf.dtype)), pool, payload)
+    into freshly allocated pages of this engine's pool. Its state leaves
+    are written by ``_write_slot_state``."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, pl_leaf, seg: pl_leaf if _is_state(path)
+        else pl_leaf.at[:, pages].set(seg.astype(pl_leaf.dtype)),
+        pool, payload)
 
 
 @dataclasses.dataclass
@@ -259,6 +305,12 @@ class BatchingEngine:
       A slot that cannot grow is preempted back to the queue head (its
       generated tokens survive via prompt-prefix replay).
 
+    A model with SSM sites is served paged only: each slot also owns a
+    row of recurrent state beside the pages. Admission writes it from the
+    prefill, release zeroes it, and prefix pages are never adopted (a
+    matched page holds no state), so every admission prefills, and a
+    preempted request resumes by re-prefilling its context.
+
     Greedy decoding (argmax) — deterministic, testable.
     """
 
@@ -275,12 +327,13 @@ class BatchingEngine:
                  scrub_on_free: bool = True,
                  device: Optional[jax.Device] = None):
         # Slot recycling relies on position-masked KV caches (stale entries
-        # carry positions > current and are masked out). SSM state has no
-        # such masking, so the engine serves attention-family models; SSM
-        # serving uses jit_serve_step directly with per-batch state resets.
-        if model.cfg.ssm is not None:
-            raise ValueError("BatchingEngine supports attention-family "
-                             "models; use jit_serve_step for SSM archs")
+        # carry positions > current and are masked out). Recurrent state
+        # has no positions: the paged layout gives it a row per slot that
+        # inactive decode rows (position -1) leave alone.
+        if model.cfg.ssm is not None and not paged:
+            raise ValueError("a model with SSM sites is served by the "
+                             "paged engine (paged=True), which keeps a "
+                             "state row per slot")
         if prefill_mode not in ("batched", "legacy"):
             raise ValueError(f"unknown prefill_mode {prefill_mode!r}")
         self.model = model
@@ -295,6 +348,11 @@ class BatchingEngine:
         self.eos_id = eos_id
         self.prefill_mode = prefill_mode
         self.paged = paged
+        # prefix pages are adopted only by the batched prefill, and only
+        # where a page is all the state a position needs
+        self._share_prefix = prefill_mode == "batched" \
+            and model.cfg.ssm is None
+        self._state_free: List[int] = []   # released slots, state unzeroed
         self._queues: "Dict[str, Deque[Request]]" = {}
         self._qlock = threading.Lock()
         self._tenant_share: Dict[str, int] = {}      # max concurrent slots
@@ -337,7 +395,13 @@ class BatchingEngine:
                                         max_blocks,
                                         scrub_on_free=scrub_on_free)
             self.caches = self._alloc(
-                lambda: model.make_paged_caches(cache_pages, page_size))
+                lambda: model.make_paged_caches(cache_pages, page_size,
+                                                n_slots))
+            # bytes of one slot's recurrent state over all SSM sites
+            self._state_row_bytes = sum(
+                leaf.nbytes // n_slots for path, leaf in
+                jax.tree_util.tree_flatten_with_path(self.caches)[0]
+                if _is_state(path))
             self._sweep_layers = paged_sweep_layers(
                 model.cfg, self.caches, n_slots, max_blocks)
             self._pos = np.full((n_slots,), -1, np.int32)
@@ -349,6 +413,7 @@ class BatchingEngine:
             self.page_size = 0
             self.cache_pages = 0
             self.pool = None
+            self._state_row_bytes = 0
             self.caches = self._alloc(
                 lambda: model.make_caches(n_slots, max_len))
             self._pos = np.zeros((n_slots,), np.int32)
@@ -516,14 +581,42 @@ class BatchingEngine:
         if self.on_finish is not None:
             self.on_finish(req)
 
-    def _release_slot(self, slot: int):
-        """Free a slot (and its pool pages) without touching the request."""
+    def _release_slot(self, slot: int, scrub: bool = True):
+        """Free a slot (and its pool pages and state row) without touching
+        the request. ``scrub=False`` leaves the state row to the caller's
+        ``_flush_state_scrub``, which zeroes several rows in one call."""
         sanitizer.emit("slot", (self._scope, slot), "release")
         self._slots[slot] = None
         self._prefilling.pop(slot, None)   # buffered prefill dies with it
         self._pos[slot] = -1 if self.paged else 0
         if self.paged:
             self.pool.release_slot(slot)
+        if self._state_row_bytes:
+            self._state_free.append(slot)
+            if scrub:
+                self._flush_state_scrub()
+
+    def _flush_state_scrub(self) -> None:
+        """Zero the state rows of the slots released since the last flush,
+        in one call of one shape: tenant isolation, so that a recycled
+        slot hands no tenant's recurrent state to the next."""
+        if not self._state_free:
+            return
+        slots, self._state_free = self._state_free, []
+        rows = np.full((self.n_slots,), slots[0], np.int32)
+        rows[:len(slots)] = slots
+        with span("rc3e.engine.state_scrub", slots=len(slots),
+                  bytes=len(slots) * self._state_row_bytes):
+            self.caches = _scrub_slot_state(self.caches, self._put(rows))
+
+    def _write_state(self, slot: int, one) -> None:
+        """Write a batch-1 tree's recurrent state into ``slot``'s row."""
+        if not self._state_row_bytes:
+            return
+        with span("rc3e.engine.state_write", slot=slot,
+                  bytes=self._state_row_bytes):
+            self.caches = _write_slot_state(self.caches, one,
+                                            self._put(np.int32(slot)))
 
     def drain_tenant(self, tenant: str) -> List[Request]:
         """Evict a tenant's in-flight and queued requests for live hand-off
@@ -589,8 +682,10 @@ class BatchingEngine:
         Callers that overwrite a whole page (batched splice, page import,
         COW copy) skip this; token-at-a-time writers (legacy prefill,
         decode into a freshly grown page) must not leave the previous
-        occupant's positions masquerading as their own history."""
-        if not self.paged or not pages:
+        occupant's positions masquerading as their own history. Under
+        zero-on-free every page was scrubbed (``pos`` -1) before it could
+        be allocated again, so there is nothing to invalidate."""
+        if not self.paged or not pages or self.pool.scrub_on_free:
             return
         self.caches = _invalidate_pool_pages(
             self.caches,
@@ -627,8 +722,7 @@ class BatchingEngine:
         if not self.paged:
             return True
         needed = self.pool.pages_needed(
-            req.tenant, self._ctx_tokens(req),
-            share=self.prefill_mode == "batched")
+            req.tenant, self._ctx_tokens(req), share=self._share_prefix)
         return needed <= self.pool.free_pages and \
             self._page_budget_ok(req.tenant, needed)
 
@@ -749,7 +843,7 @@ class BatchingEngine:
         if self.paged:
             self._flush_scrub()
             plan = self.pool.admit(slot, req.tenant, toks,
-                                   share=self.prefill_mode == "batched")
+                                   share=self._share_prefix)
         buf = None
         chunks = 0
         if plan is not None and plan.skip_prefill:
@@ -780,6 +874,7 @@ class BatchingEngine:
         if pending.buf is not None:
             if self.paged:
                 self._splice_paged(pending.buf, pending.plan)
+                self._write_state(slot, pending.buf)
             else:
                 with span("rc3e.engine.splice"):
                     self.caches = self._splice(self.caches, pending.buf,
@@ -795,7 +890,7 @@ class BatchingEngine:
         (writes at every position), so it must not adopt shared pages."""
         self._flush_scrub()
         plan = self.pool.admit(slot, req.tenant, toks,
-                               share=self.prefill_mode == "batched")
+                               share=self._share_prefix)
         ctx = toks[:-1]
         if not plan.skip_prefill:
             if len(ctx) >= self.PREFILL_MIN_TOKENS \
@@ -822,8 +917,11 @@ class BatchingEngine:
                             plan):
         """Prefill, then scatter ONLY the unshared suffix blocks into this
         slot's pool pages (shared prefix pages already hold identical
-        content — that's the point of sharing them)."""
-        self._splice_paged(self._run_prefill(req, ctx), plan)
+        content — that's the point of sharing them), and write the slot's
+        recurrent state."""
+        buf = self._run_prefill(req, ctx)
+        self._splice_paged(buf, plan)
+        self._write_state(slot, buf)
 
     def _run_prefill(self, req: Request, ctx: np.ndarray):
         """Dispatch the batched prefill of ``ctx``; returns its batch-1
@@ -846,12 +944,15 @@ class BatchingEngine:
                                         start=plan.write_start)
 
     def _pad_ctx(self, ctx: np.ndarray):
+        """The context padded to its bucket. A model with SSM sites pads
+        with -1, which its prefill masks out of the recurrent state."""
         n = len(ctx)
         bucket = 8
         while bucket < n:
             bucket *= 2
         pad = max(n, min(bucket, self._min_cache_len))
-        toks = np.zeros((1, pad), np.int32)
+        toks = np.full((1, pad), -1 if self._state_row_bytes else 0,
+                       np.int32)
         toks[0, :n] = ctx
         # prefill prompt upload: once per admission, not per step
         return self._put(toks)
@@ -987,8 +1088,14 @@ class BatchingEngine:
         if not active:
             return 0
         with span("rc3e.engine.decode_dispatch") as sp:
+            # one position per state row the step reads and writes: an
+            # SSM site steps every row it is given, live or not
+            rows = self._pos
             if sp and self.paged:
                 sp.set(**self._sweep_counts())
+                if self._state_row_bytes:
+                    sp.set(state_rows=len(rows),
+                           state_rows_held=self.n_slots)
             tokens = np.zeros((self.n_slots, 1), np.int32)
             for i in active:
                 tokens[i, 0] = self._slots[i]._next_input
@@ -999,7 +1106,7 @@ class BatchingEngine:
             if self.paged:
                 logits, self.caches = self._decode(
                     self.params, self.caches, self._put(tokens),
-                    self._put(self._pos), self._block_tables_dev())
+                    self._put(rows), self._block_tables_dev())
             else:
                 logits, self.caches = self._decode(
                     self.params, self.caches, self._put(tokens),
@@ -1025,8 +1132,9 @@ class BatchingEngine:
                 eos = self.eos_id is not None and nxt == self.eos_id
                 if len(req.out_tokens) >= req.max_new_tokens or eos \
                         or self._pos[i] >= self.max_len - 1:
-                    self._release_slot(i)
+                    self._release_slot(i, scrub=False)
                     self._finish(req, "eos" if eos else "length")
+            self._flush_state_scrub()
         return len(active)
 
     def idle(self) -> bool:
@@ -1060,9 +1168,10 @@ class BatchingEngine:
 
     def export_request_pages(self, req: Request):
         """Gather an in-flight request's pool pages to host memory for a
-        live hand-off (leaves (L, nb, ps, ...)). Call BEFORE draining —
-        released pages may be recycled by the next admission. Returns None
-        when the request holds no slot or the engine is dense."""
+        live hand-off (leaves (L, nb, ps, ...)), with its slot's recurrent
+        state (leaves (L, 1, ...)). Call BEFORE draining — released pages
+        may be recycled by the next admission. Returns None when the
+        request holds no slot or the engine is dense."""
         if not self.paged:
             return None
         for i, r in enumerate(self._slots):
@@ -1071,8 +1180,10 @@ class BatchingEngine:
                 if not pages:
                     return None
                 idx = np.asarray(pages, np.int32)
-                return jax.tree.map(lambda a: np.asarray(a[:, idx]),
-                                    self.caches)
+                return jax.tree_util.tree_map_with_path(
+                    lambda path, a: np.asarray(
+                        a[:, i:i + 1] if _is_state(path) else a[:, idx]),
+                    self.caches)
         return None
 
     def import_request_pages(self, req: Request, payload,
@@ -1095,21 +1206,23 @@ class BatchingEngine:
         # at the SOURCE pool's page size on a pool tuned to a different
         # one — the pages cannot be adopted page-for-page, so decline and
         # let the caller fall back to prefix replay (bit-exact greedy)
-        if jax.tree.leaves(payload)[0].shape[2] != self.page_size:
+        page = _page_leaves(payload)[0]
+        if page.shape[2] != self.page_size:
             return False
         slot = next((i for i, r in enumerate(self._slots) if r is None),
                     None)
         if slot is None:
             return False
-        nb = jax.tree.leaves(payload)[0].shape[1]
+        nb = page.shape[1]
         if nb > self.pool.free_pages or \
                 not self._page_budget_ok(req.tenant, nb):
             return False
         self._flush_scrub()
         pages = [self.pool.grow(slot, req.tenant) for _ in range(nb)]
+        payload = self._put(payload)
         self.caches = _import_pages(
-            self.caches, self._put(payload),
-            self._put(np.asarray(pages, np.int32)))
+            self.caches, payload, self._put(np.asarray(pages, np.int32)))
+        self._write_state(slot, payload)
         toks = self._ctx_tokens(req)
         base = len(toks) if ctx_len is None else int(ctx_len)
         # catch-up: KV for positions 0..base-2 arrived with the snapshot;
@@ -1126,6 +1239,9 @@ class BatchingEngine:
                     # can't cover the delta — roll the adoption back and
                     # let the caller fall back to prefix replay
                     self.pool.release_slot(slot)
+                    if self._state_row_bytes:
+                        self._state_free.append(slot)
+                        self._flush_state_scrub()
                     return False
             self._step_single(slot, int(t), pos)
         self._slots[slot] = req

@@ -159,3 +159,34 @@ def test_phi3_paged_decode_step_sweeps_pages_in_bf16(one_chip):
               if dtype == "f32" and dims[-2:] == (kv, hd)
               and np.prod(dims) >= page_of_batch]
     assert f32_kv == []
+
+
+def test_granite_hybrid_paged_programs(one_chip):
+    """granite-4.0-h-micro at its published widths and depth as the
+    benchmark deploys it (16 slots, 1025 pages of 64, 6528 positions): the
+    paged decode step, whose caches tree holds each Mamba-2 layer's
+    per-slot state beside the attention layers' pages, and the prefill at
+    its longest bucket, 6528 tokens padded with -1, which is no multiple
+    of the attention's query chunk or of the SSD chunk."""
+    from repro.runtime.serve import make_prefill_step
+    cfg = get_config("granite-4.0-h-micro").replace(param_dtype="bfloat16")
+    model = get_model(cfg)
+    slots, max_len, page, pages = 16, 6528, 64, 1025
+    params = _placed(one_chip, jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0))))
+    pool = jax.eval_shape(
+        lambda: model.make_paged_caches(pages, page, slots))
+    state = [leaf for path, leaf in jax.tree_util.tree_flatten_with_path(
+        pool)[0] if getattr(path[-1], "key", None) == "state"]
+    assert sum(leaf.size for leaf in state) == 36 * slots * 64 * 64 * 128
+    args = (params, _placed(one_chip, pool),
+            _sds(one_chip, (slots, 1), jnp.int32),
+            _sds(one_chip, (slots,), jnp.int32),
+            _sds(one_chip, (slots, max_len // page), jnp.int32))
+    decode = jax.jit(make_paged_serve_step(model)).lower(*args).compile()
+    assert decode.memory_analysis().argument_size_in_bytes < 9 * 2 ** 30
+    step = make_prefill_step(model, max_len, clamp_window=False)
+    prefill = jax.jit(lambda p, t: step(p, {"tokens": t})).lower(
+        params, _sds(one_chip, (1, max_len), jnp.int32)).compile()
+    mem = prefill.memory_analysis()
+    assert mem.temp_size_in_bytes < 4 * 2 ** 30

@@ -79,15 +79,41 @@ def test_fleet_engines_share_one_decode_program(served_model):
 
 
 def test_fleet_rejects_ssm_before_any_allocation():
-    """The engine-family restriction must surface at construction, not
-    from lazy engine creation inside open_session (which would strand an
-    admitted tenant and its vSlice)."""
+    """The engine-layout restriction (SSM state lives only beside a paged
+    pool) must surface at construction, not from lazy engine creation
+    inside open_session (which would strand an admitted tenant and its
+    vSlice)."""
     cfg = reduced(get_config("mamba2-370m")).replace(dtype="float32")
     model = get_model(cfg)
     hv = Hypervisor(ClusterSpec(n_nodes=1, devices_per_node=1))
-    with pytest.raises(ValueError, match="attention-family"):
+    with pytest.raises(ValueError, match="paged"):
         GatewayFleet(hv, model, model.init(jax.random.PRNGKey(0)))
     assert all(u == 0.0 for u in hv.db.utilization().values())
+
+
+def test_session_share_scales_with_engine_width(served_model):
+    """A vSlice holds k of a device's four slots. On an engine of 16
+    slots its session may hold 4k of them at once, with the same share of
+    the page pool; on an engine of at most four it holds k, as before."""
+    cfg, model, params = served_model
+    hv = Hypervisor(ClusterSpec(n_nodes=1, devices_per_node=1))
+    fleet = GatewayFleet(hv, model, params, n_slots=16, max_len=64,
+                         paged=True, page_size=16, cache_pages=65)
+    fleet.open_session("a", slots=1, service_model="raas")
+    eng = fleet.engine_for("a")
+    assert eng._tenant_share["a"] == 4 and eng._tenant_pages["a"] == 16
+    for i in range(6):
+        fleet.submit("a", _prompt(cfg, seed=i), max_new_tokens=4)
+    fleet.step()
+    assert eng.active_by_tenant()["a"] == 4
+    assert fleet.run_until_idle()
+    fleet.close()
+    narrow = GatewayFleet(Hypervisor(ClusterSpec(n_nodes=1,
+                                                 devices_per_node=1)),
+                          model, params, n_slots=2, max_len=64)
+    narrow.open_session("b", slots=1)
+    assert narrow.engine_for("b")._tenant_share["b"] == 1
+    narrow.close()
 
 
 def test_open_session_failure_unwinds_allocation(served_model, monkeypatch):

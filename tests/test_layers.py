@@ -92,6 +92,22 @@ def test_ssd_state_carry_equals_concat():
                                atol=1e-4, rtol=1e-3)
 
 
+def test_fresh_mamba2_layer_is_live():
+    """init_ssm stores the gated RMSNorm's weight as w - 1 (zeros) and the
+    layer applies 1 + w: a freshly initialised Mamba-2 layer gives a
+    non-zero output at every position, in the prefill and in decode."""
+    from repro.layers.ssm import init_ssm_cache, ssm_decode
+    opts = SSMOpts(d_model=64, cfg=SSMConfig(d_state=16, head_dim=16,
+                                             chunk=8))
+    p = init_ssm(jax.random.PRNGKey(0), opts)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 12, 64))
+    y, _ = ssm_forward(p, x, opts)
+    assert float(jnp.abs(y).max(axis=-1).min()) > 1e-3
+    yd, _ = ssm_decode(p, x[:, :1], init_ssm_cache(2, opts, jnp.float32),
+                       opts)
+    assert float(jnp.abs(yd).max(axis=-1).min()) > 1e-3
+
+
 def test_local_attention_equals_masked_full():
     """Sliding-window path (key slicing) == full attention with window mask."""
     opts_local = AttnOpts(n_heads=4, n_kv_heads=2, head_dim=16, window=8,
